@@ -93,6 +93,9 @@ def write_artifact(mod_name: str, rows, out_dir: pathlib.Path) -> pathlib.Path:
 
 
 def main() -> None:
+    from repro.utils import enable_compile_cache
+
+    enable_compile_cache()
     only = sys.argv[1:] or MODULES
     out_dir = _artifact_dir()
     print("name,us_per_call,derived")

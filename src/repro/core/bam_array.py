@@ -299,7 +299,7 @@ class BamArray:
     # and the weighted-fair arbitration orders a real mixed stream.
     defer_drain: bool = False
     # Kernel dispatch policy for the probe / fused probe+allocate / gather
-    # hot path: "auto" (Pallas on TPU, jnp-oracle XLA elsewhere),
+    # hot path: "auto" (the static rule of repro.kernels.ops.resolve_impl),
     # "pallas", or "ref" — threaded to repro.kernels.ops on every op.
     kernel_impl: str = "auto"
     # Fully traced I/O rounds (default): submit's multi-segment SQ enqueue
@@ -340,9 +340,10 @@ class BamArray:
         multiple of the device count so every channel gets the same depth.
 
         ``kernel_impl`` picks the hot-path kernels (probe, fused
-        probe+allocate, line gather): ``"auto"`` compiles the Pallas
-        kernels natively on TPU and runs the bit-identical jnp oracles as
-        XLA graphs elsewhere; ``"pallas"``/``"ref"`` pin one side (tests
+        probe+allocate, line gather): ``"auto"`` picks per kernel by the
+        static rule of :func:`repro.kernels.ops.resolve_impl` (Pallas for
+        the probe and the gather on TPU, the bit-identical jnp oracles
+        elsewhere); ``"pallas"``/``"ref"`` pin one side (tests
         pin ``"pallas"`` with interpret mode for the differential sweeps).
         """
         import numpy as np
@@ -712,8 +713,8 @@ class BamArray:
             return self._submit_prefetch(st, co, off, valid)
 
         # 2+3) fused probe + victim allocate: ONE kernel pass
-        #    (repro.kernels.ops.probe_allocate, Pallas on TPU / jnp oracle
-        #    elsewhere) probes the tags and grants a victim slot per miss.
+        #    (repro.kernels.ops.probe_allocate, the jnp oracle under
+        #    impl="auto") probes the tags and grants a victim slot per miss.
         #    This round's hits are protected in-pass; lines pinned by
         #    other outstanding tokens are refcount-protected.
         cache2, pr, alloc = C.probe_allocate(

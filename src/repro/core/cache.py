@@ -137,7 +137,8 @@ def probe(cache: CacheState, keys: jax.Array,
 
     The tag compare is dispatched through :mod:`repro.kernels.ops`
     (``impl="auto"``: the Pallas one-hot-matmul probe on TPU, the
-    bit-identical jnp oracle as an XLA graph elsewhere).
+    bit-identical jnp oracle as an XLA graph elsewhere —
+    :func:`repro.kernels.ops.resolve_impl`).
     """
     if valid is None:
         valid = keys >= 0

@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 
 __all__ = ["gpipe"]
 
@@ -82,7 +81,7 @@ def gpipe(stage_fn: Callable, n_stages: int, n_microbatches: int, *,
                 jnp.where(stage == n_stages - 1, outs, 0.0), axis)
             return outs.reshape(B, *x_all.shape[1:])
 
-        return compat.shard_map(
+        return jax.shard_map(
             per_stage, mesh=mesh,
             in_specs=(P(axis), P()), out_specs=P(),
             axis_names={axis}, check_vma=False,

@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -181,7 +180,7 @@ def flash_attention_pallas(
             kernel,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "parallel",
                                      "arbitrary")),
             interpret=interpret,
@@ -206,7 +205,7 @@ def flash_attention_pallas(
         out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=scratch,
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

@@ -3,13 +3,22 @@
 Backend policy: on TPU the Pallas kernels compile natively; everywhere else
 (this CPU container) they run under ``interpret=True``, which executes the
 kernel body in Python per grid step — bit-faithful, slow.  Because interpret
-mode is too slow for the big model graphs, the model code calls these
-wrappers with ``impl='auto'`` which picks:
+mode is too slow for the big model graphs, the callers pass ``impl='auto'``,
+which :func:`resolve_impl` turns into one implementation by a single static
+rule:
 
-  * 'pallas'    on TPU backends,
-  * 'ref'       (the pure-jnp oracle, an XLA graph) elsewhere — so smoke
-                tests and the CPU dry-run use honest XLA HLO that
-                ``cost_analysis()`` can account.
+  * ``'pallas'`` on a TPU backend for the kernels in :data:`PALLAS_ON_TPU`,
+  * ``'ref'`` (the pure-jnp oracle, an XLA graph) for every other kernel
+    and on every other backend — so smoke tests and the CPU dry-run use
+    honest XLA HLO that ``cost_analysis()`` can account.
+
+``probe_allocate`` is not in the set: its single-block kernel keeps an
+``(m, num_sets)`` one-hot and an ``(m, m)`` rank matrix in VMEM; for a
+4096-lane wavefront the rank matrix alone is 64 MiB, and the v5e compiler
+refuses the kernel.  The compile rehearsals in
+``tests/test_tpu_compile.py`` compile what this rule picks at deployment
+shapes.  Nothing catches a compile error to swap implementations at run
+time.
 
 Tests pin ``impl='pallas', interpret=True`` and sweep shapes/dtypes against
 ``impl='ref'``.
@@ -32,20 +41,36 @@ from repro.kernels.probe_allocate import probe_allocate_pallas
 Impl = Literal["auto", "pallas", "ref"]
 
 
+# Kernels that run as Pallas under impl="auto" on a TPU backend.  The BaM
+# hot-path entries compile for v5e at deployment shapes; the attention
+# kernels serve the LM stack, outside the BaM request path.
+PALLAS_ON_TPU = frozenset({"gather_blocks", "cache_probe",
+                           "flash_attention", "paged_attention"})
+
+
+def _platform() -> str:
+    return jax.default_backend()
+
+
 def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+    return _platform() == "tpu"
 
 
-def _resolve(impl: Impl) -> str:
-    if impl == "auto":
-        return "pallas" if _on_tpu() else "ref"
-    return impl
+def resolve_impl(kernel: str, impl: Impl = "auto",
+                 platform: str | None = None) -> str:
+    """The implementation ``kernel`` runs under ``impl`` on ``platform``
+    (default: JAX's default backend) — see the module docstring's rule."""
+    if impl != "auto":
+        return impl
+    platform = _platform() if platform is None else platform
+    return "pallas" if platform == "tpu" and kernel in PALLAS_ON_TPU \
+        else "ref"
 
 
 def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
                     block_q=256, block_kv=256, tile_f32: bool = True,
                     impl: Impl = "auto", interpret: bool | None = None):
-    if _resolve(impl) == "ref":
+    if resolve_impl("flash_attention", impl) == "ref":
         # blockwise XLA path once the score matrix would exceed ~16M elems
         # per (batch, head) — bounded memory for the 32k/500k cells.
         if q.shape[2] * k.shape[2] > (1 << 22):
@@ -62,7 +87,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, scale=None,
 
 def paged_attention(q, k_pages, v_pages, page_table, seq_lens, *, scale=None,
                     impl: Impl = "auto", interpret: bool | None = None):
-    if _resolve(impl) == "ref":
+    if resolve_impl("paged_attention", impl) == "ref":
         return _ref.paged_attention_ref(q, k_pages, v_pages, page_table,
                                         seq_lens, scale=scale)
     itp = (not _on_tpu()) if interpret is None else interpret
@@ -77,7 +102,7 @@ def gather_blocks(data, slots, *, off=None, impl: Impl = "auto",
     line-granular anyway) and selects the element after; the ref/XLA path
     gathers just the elements.
     """
-    if _resolve(impl) == "ref":
+    if resolve_impl("gather_blocks", impl) == "ref":
         return _ref.gather_blocks_ref(data, slots, off=off)
     itp = (not _on_tpu()) if interpret is None else interpret
     lines = gather_blocks_pallas(data, slots, interpret=itp)
@@ -88,7 +113,7 @@ def gather_blocks(data, slots, *, off=None, impl: Impl = "auto",
 
 def cache_probe(tags, keys, *, owner=None, tenant=0, block_m=512,
                 impl: Impl = "auto", interpret: bool | None = None):
-    if _resolve(impl) == "ref":
+    if resolve_impl("cache_probe", impl) == "ref":
         return _ref.cache_probe_ref(tags, keys, owner=owner, tenant=tenant)
     itp = (not _on_tpu()) if interpret is None else interpret
     return cache_probe_pallas(tags, keys, owner=owner, tenant=tenant,
@@ -144,7 +169,7 @@ def probe_allocate(tags, owner, refcount, dirty, speculative, clock_hand,
     """
     if valid is None:
         valid = keys >= 0
-    if _resolve(impl) == "ref":
+    if resolve_impl("probe_allocate", impl) == "ref":
         return _ref.probe_allocate_ref(
             tags, owner, refcount, dirty, speculative, clock_hand, keys,
             valid, alloc_mask, protect_slots, tenant=tenant, way_lo=way_lo,

@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST be the first lines, before any other import: jax locks the device
 #   count at first init.  512 placeholder host devices back the production
-#   meshes (16x16 single-pod slice, 2x16x16 multi-pod).
+#   meshes (16x16 single-pod slice, 2x16x16 multi-pod).  The CPU platform
+#   is pinned so neither this process nor the per-cell children it starts
+#   (which inherit the environment) can claim an attached TPU.
 
 """Multi-pod dry-run: ``lower().compile()`` every (arch x shape x mesh) cell.
 

@@ -1,5 +1,6 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"  # a CPU dry-run: never claim a TPU
 
 """§Perf hillclimbing: hypothesis -> change -> re-lower -> re-analyse.
 

@@ -26,7 +26,6 @@ from repro.kernels import ops
 from repro.models import layers as L
 from repro.utils import Tagged
 
-from repro import compat
 
 BIG_WINDOW = 1 << 30
 
@@ -406,7 +405,7 @@ def _paged_attention_flash_decode(cfg, q, k_pages, v_pages, page_table,
         out = acc / jnp.maximum(l, 1e-30)[..., None]
         return out.reshape(B, Hq, D).astype(q.dtype)
 
-    return compat.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(PS(), PS(None, "model"), PS(None, "model"), PS(), PS()),
         out_specs=PS(), axis_names={"model"}, check_vma=False,

@@ -18,7 +18,6 @@ from repro.distributed import sharding as shd
 from repro.models.model import ModelApi, build_model
 from repro.training import optimizer as opt
 
-from repro import compat
 
 TrainState = dict  # {"params": ..., "opt": ...}
 
@@ -112,7 +111,7 @@ def make_train_step(cfg: ArchConfig, api: Optional[ModelApi] = None, *,
     def train_step(state: TrainState, batch):
         batch_specs = jax.tree_util.tree_map(lambda _: PS("pod"), batch)
         state_specs = jax.tree_util.tree_map(lambda _: PS(), state)
-        return compat.shard_map(
+        return jax.shard_map(
             per_pod, mesh=mesh_,
             in_specs=(state_specs, batch_specs),
             out_specs=(state_specs, PS()),

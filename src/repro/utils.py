@@ -2,6 +2,8 @@
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 from typing import Any, TypeVar
 
 import jax
@@ -34,6 +36,22 @@ def pytree_dataclass(cls: type | None = None, *, meta_fields: tuple[str, ...] = 
     if cls is None:
         return wrap
     return wrap(cls)
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already keeps the cache
+    there and nothing is set here.  Otherwise the cache goes to the fixed
+    ``<checkout>/.jax_cache`` (gitignored): the path is part of the cache
+    key, so it never moves between runs.
+    """
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(pathlib.Path(__file__).resolve().parents[2] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def replace(obj: T, **kwargs: Any) -> T:

@@ -185,7 +185,7 @@ def test_bam502_traced_f64_caught_even_when_optimized_out():
     def leaky(x):
         return x + x.astype(jnp.float64).astype(jnp.float32)
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         lowered = jax.jit(leaky).lower(
             jax.ShapeDtypeStruct((8,), jnp.float32))
         traced_f64 = "f64" in lowered.as_text()

@@ -86,7 +86,6 @@ def test_pod_compressed_reduction():
         import numpy as np
         import jax, jax.numpy as jnp
         from jax.sharding import PartitionSpec as P, NamedSharding
-        from repro import compat
         from repro.launch.mesh import make_mesh
         from repro.training import optimizer as opt
 
@@ -100,7 +99,7 @@ def test_pod_compressed_reduction():
                                                 axis="pod")
             return mean["w"], ef2["w"]
 
-        f = jax.jit(compat.shard_map(
+        f = jax.jit(jax.shard_map(
             per_pod, mesh=mesh,
             in_specs=(P("pod"), P()), out_specs=(P(), P("pod")),
             axis_names={"pod"}, check_vma=False))

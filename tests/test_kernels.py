@@ -69,7 +69,8 @@ def test_paged_attention_sweep(B, Hq, Hkv, D, P, page, NP, dtype):
 
 
 @pytest.mark.parametrize("n,lines,elems", [(7, 16, 32), (64, 8, 128),
-                                           (1, 4, 8)])
+                                           (1, 4, 8), (9, 13, 128),
+                                           (40, 21, 256)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, jnp.int32])
 def test_gather_blocks_sweep(n, lines, elems, dtype):
     rng = np.random.default_rng(2)
@@ -113,6 +114,26 @@ def test_cache_probe_sweep(sets, ways, m):
     np.testing.assert_array_equal(np.asarray(s_pal), np.asarray(s_ref))
 
 
+@pytest.mark.parametrize("sets,ways,m,block_s", [(20, 4, 50, 8),
+                                                 (64, 2, 100, 16)])
+def test_cache_probe_set_tiled(sets, ways, m, block_s):
+    """Directories larger than one set block (the deployment case): the
+    grid walks set slices, the last one padded, with the owner check."""
+    from repro.kernels.cache_probe import cache_probe_pallas
+    rng = np.random.default_rng(7)
+    tags = jnp.asarray(rng.integers(-1, 5000, (sets, ways)), jnp.int32)
+    owner = jnp.asarray(rng.integers(0, 2, (sets, ways)), jnp.int32)
+    keys = jnp.concatenate([
+        tags.reshape(-1)[:m // 2],
+        jnp.asarray(rng.integers(-1, 10000, m - m // 2), jnp.int32)])
+    h_pal, s_pal = cache_probe_pallas(tags, keys, owner=owner, tenant=1,
+                                      block_m=16, block_s=block_s,
+                                      interpret=True)
+    h_ref, s_ref = ref.cache_probe_ref(tags, keys, owner=owner, tenant=1)
+    np.testing.assert_array_equal(np.asarray(h_pal), np.asarray(h_ref))
+    np.testing.assert_array_equal(np.asarray(s_pal), np.asarray(s_ref))
+
+
 def test_cache_probe_matches_core_cache():
     """The kernel is bit-identical to the functional cache's probe."""
     from repro.core import cache as C
@@ -145,12 +166,11 @@ def test_flash_xla_backward_stays_f32_under_x64():
     """Regression (bamlint BAM303): the manual backward's dk/dv scan
     accumulators were built without a dtype — float64 under x64 — which
     promoted (or broke) the whole custom-vjp backward."""
-    import jax.experimental
     rng = np.random.default_rng(6)
     q = _mk(rng, (1, 2, 32, 16), jnp.float32)
     k = _mk(rng, (1, 2, 32, 16), jnp.float32)
     v = _mk(rng, (1, 2, 32, 16), jnp.float32)
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         dq, dk, dv = jax.grad(
             lambda q, k, v: ref.flash_attention_xla(
                 q, k, v, causal=True, block_q=16, block_kv=16).sum(),

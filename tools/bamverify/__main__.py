@@ -22,7 +22,7 @@ import sys
 from tools.bamverify import ALL_RULES
 from tools.bamverify.manifest import (
     MANIFEST_PATH, diff_manifest, entry_from_stats, load_manifest,
-    save_manifest,
+    manifest_jax_version, save_manifest,
 )
 from tools.bamverify.rules import check_artifact
 
@@ -79,8 +79,9 @@ def main(argv=None) -> int:
         return 2
 
     current = {key: entry_from_stats(s) for key, s in stats.items()}
+    import jax
     if args.update_manifest:
-        save_manifest(current, args.manifest)
+        save_manifest(current, args.manifest, jax_version=jax.__version__)
         print(f"bamverify: wrote {len(current)} artifact entr(ies) to "
               f"{args.manifest}")
 
@@ -98,6 +99,11 @@ def main(argv=None) -> int:
         print(f.render())
     for line in drift:
         print(f"manifest drift: {line}")
+    lowered_under = manifest_jax_version(args.manifest)
+    if drift and lowered_under != jax.__version__:
+        print(f"bamverify: the manifest was lowered under jax "
+              f"{lowered_under}, this is jax {jax.__version__}: "
+              "read the drift as a toolchain change before regenerating")
     n = len(findings) + len(drift)
     if n:
         print(f"\nbamverify: {len(findings)} rule finding(s), "

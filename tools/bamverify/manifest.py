@@ -44,9 +44,18 @@ def load_manifest(path: pathlib.Path = MANIFEST_PATH) -> Dict[str, Dict]:
     return data.get("ops", {})
 
 
+def manifest_jax_version(path: pathlib.Path = MANIFEST_PATH) -> str | None:
+    """The JAX version the manifest's artifacts were lowered under."""
+    if not path.is_file():
+        return None
+    return json.loads(path.read_text()).get("jax")
+
+
 def save_manifest(entries: Dict[str, Dict],
-                  path: pathlib.Path = MANIFEST_PATH) -> None:
-    payload = {"version": 1, "ops": {k: entries[k] for k in sorted(entries)}}
+                  path: pathlib.Path = MANIFEST_PATH, *,
+                  jax_version: str) -> None:
+    payload = {"version": 1, "jax": jax_version,
+               "ops": {k: entries[k] for k in sorted(entries)}}
     path.write_text(json.dumps(payload, indent=2) + "\n")
 
 
