@@ -35,7 +35,7 @@ repo's request path, at several wavefront sizes:
 
 All numbers are host wall-clock µs per call (``time_us`` blocks on every
 iteration's output), with derived ops/sec.  The driver (`run.py`) writes
-them to ``BENCH_hot_path.json`` — the repo's measured perf trajectory.
+them to ``BENCH_hot_path.json``, a machine-local artifact (not committed).
 
 Standalone (``python benchmarks/hot_path.py``) prints a JSON report and
 exits nonzero unless (the PR acceptance gate, CI-runnable):

@@ -64,6 +64,7 @@ exactly to the global counters.
 from __future__ import annotations
 
 import dataclasses
+import re
 from typing import Any, Dict, Optional, Sequence, Tuple
 
 import jax
@@ -94,6 +95,12 @@ __all__ = ["BamArray", "BamState", "BamKVStore", "PrefetchConfig",
 DEFAULT_BUCKETS = (64, 256, 1024, 4096)
 
 
+def _op_name(key: str) -> str:
+    """The jitted op's name for jit-cache key ``key``: ``"submit[donated]"``
+    -> ``"bam_submit_donated"``, ``"read:a"`` -> ``"bam_read_a"``."""
+    return "bam_" + "_".join(re.findall(r"[A-Za-z0-9]+", key))
+
+
 def _cached_jit(cache: Dict[str, Any], counts: Dict[str, int], key: str,
                 make, donate_argnums=()):
     """One ``jax.jit`` per op key, cached in ``cache``; jit itself keys
@@ -112,6 +119,11 @@ def _cached_jit(cache: Dict[str, Any], counts: Dict[str, int], key: str,
     ``QueueState`` in place instead of copying them.  The caller must not
     touch a donated value afterwards (JAX raises on reuse; bamlint rule
     BAM106 flags it statically).
+
+    The traced callable is named after the key (:func:`_op_name`), so the
+    jitted op and every device op it holds carry ``jit(bam_submit...)``,
+    ``jit(bam_wait...)`` ... in the profiler's trace, and the stage scopes
+    of :meth:`BamArray.submit` / :meth:`BamArray.wait_ex` sit below it.
     """
     fn = cache.get(key)
     if fn is None:
@@ -121,6 +133,7 @@ def _cached_jit(cache: Dict[str, Any], counts: Dict[str, int], key: str,
             counts[_key] = counts.get(_key, 0) + 1
             return _raw(*args, **kw)
 
+        counted.__name__ = counted.__qualname__ = _op_name(key)
         # this IS the per-instance jit cache the rule points at
         fn = jax.jit(counted,  # bamlint: ignore[BAM105]
                      donate_argnums=tuple(donate_argnums))
@@ -693,22 +706,23 @@ class BamArray:
             raise ValueError("IORequest(kind='write') needs values")
         if req.idx.shape[0] == 0:
             return self._submit_empty(st, req)
-        idx = req.idx
-        valid = req.valid
-        if valid is None:
-            valid = (idx >= 0) & (idx < self.size)
-        blk, off = self._split(jnp.where(valid, idx, 0))
-        blk = jnp.where(valid, blk, -1)
+        with jax.named_scope("coalesce"):
+            idx = req.idx
+            valid = req.valid
+            if valid is None:
+                valid = (idx >= 0) & (idx < self.size)
+            blk, off = self._split(jnp.where(valid, idx, 0))
+            blk = jnp.where(valid, blk, -1)
 
-        # 1) warp-coalesce the wavefront to unique cache lines.
-        co = coalesce(blk, valid)
-        ukeys = co.unique_keys                      # (n,) padded with -1
-        uvalid = ukeys >= 0
-        ctx = self.tenant_ctx
-        nd = self.ssd.n_devices
-        sb = self.ssd.stripe_blocks
-        fd = self.ssd.fault.failed_devices
-        mt = st.metrics
+            # 1) warp-coalesce the wavefront to unique cache lines.
+            co = coalesce(blk, valid)
+            ukeys = co.unique_keys                      # (n,) padded with -1
+            uvalid = ukeys >= 0
+            ctx = self.tenant_ctx
+            nd = self.ssd.n_devices
+            sb = self.ssd.stripe_blocks
+            fd = self.ssd.fault.failed_devices
+            mt = st.metrics
         if kind == "prefetch":
             return self._submit_prefetch(st, co, off, valid)
 
@@ -717,92 +731,98 @@ class BamArray:
         #    impl="auto") probes the tags and grants a victim slot per miss.
         #    This round's hits are protected in-pass; lines pinned by
         #    other outstanding tokens are refcount-protected.
-        cache2, pr, alloc = C.probe_allocate(
-            st.cache, ukeys, uvalid, tenant=ctx.tenant,
-            way_lo=ctx.way_lo, way_hi=ctx.way_hi, impl=self.kernel_impl)
+        with jax.named_scope("probe_allocate"):
+            cache2, pr, alloc = C.probe_allocate(
+                st.cache, ukeys, uvalid, tenant=ctx.tenant,
+                way_lo=ctx.way_lo, way_hi=ctx.way_hi, impl=self.kernel_impl)
 
-        #    Demand probe accounting.  A hit on a prefetched line promotes
-        #    it; a hit on an *in-flight* line is a cross-op coalesce — some
-        #    pending token already has the fetch in the rings, so this op
-        #    rides that command instead of issuing its own.  (Hit slots and
-        #    granted slots are disjoint, so promoting after the allocation
-        #    scatters is bit-identical to promoting before them.)
-        n_hit = jnp.sum(pr.hit.astype(jnp.int32))
-        n_pref_hit = jnp.sum(pr.speculative.astype(jnp.int32))
-        n_cross = jnp.sum(pr.inflight.astype(jnp.int32))
-        miss = uvalid & ~pr.hit
+            #    Demand probe accounting.  A hit on a prefetched line
+            #    promotes it; a hit on an *in-flight* line is a cross-op
+            #    coalesce — some pending token already has the fetch in the
+            #    rings, so this op rides that command instead of issuing its
+            #    own.  (Hit slots and granted slots are disjoint, so
+            #    promoting after the allocation scatters is bit-identical to
+            #    promoting before them.)
+            n_hit = jnp.sum(pr.hit.astype(jnp.int32))
+            n_pref_hit = jnp.sum(pr.speculative.astype(jnp.int32))
+            n_cross = jnp.sum(pr.inflight.astype(jnp.int32))
+            miss = uvalid & ~pr.hit
 
-        # 3b) pin everything this token touched until its wait, and mark
-        #     granted (not-yet-filled) lines in flight.  The four
-        #     bookkeeping steps (hit count, promote, pin, in-flight) touch
-        #     disjoint cache fields, so the fused path folds them into one
-        #     CacheState rebuild — bit-identical to the sequential helpers.
-        pin_slots = jnp.where(pr.hit, pr.slot,
-                              jnp.where(alloc.ok, alloc.slot, -1))
-        grant_slots = jnp.where(alloc.ok, alloc.slot, -1)
-        promote_slots = jnp.where(pr.speculative, pr.slot, -1)
-        if self.fused_rounds:
-            cache2 = C.grant_bookkeeping(cache2, n_hit, promote_slots,
-                                         pin_slots, grant_slots)
-        else:
-            cache2 = C.count_hits(cache2, n_hit)
-            cache2 = C.promote(cache2, promote_slots)
-            cache2 = C.acquire(cache2, pin_slots)
-            cache2 = C.mark_inflight(cache2, grant_slots)
+            # 3b) pin everything this token touched until its wait, and mark
+            #     granted (not-yet-filled) lines in flight.  The four
+            #     bookkeeping steps (hit count, promote, pin, in-flight)
+            #     touch disjoint cache fields, so the fused path folds them
+            #     into one CacheState rebuild — bit-identical to the
+            #     sequential helpers.
+            pin_slots = jnp.where(pr.hit, pr.slot,
+                                  jnp.where(alloc.ok, alloc.slot, -1))
+            grant_slots = jnp.where(alloc.ok, alloc.slot, -1)
+            promote_slots = jnp.where(pr.speculative, pr.slot, -1)
+            if self.fused_rounds:
+                cache2 = C.grant_bookkeeping(cache2, n_hit, promote_slots,
+                                             pin_slots, grant_slots)
+            else:
+                cache2 = C.count_hits(cache2, n_hit)
+                cache2 = C.promote(cache2, promote_slots)
+                cache2 = C.acquire(cache2, pin_slots)
+                cache2 = C.mark_inflight(cache2, grant_slots)
 
         # 4) evicted dirty lines -> write-back commands + immediate DMA
         #    (the line leaves the cache now, so its bytes must be persisted
         #    now; only the *fetch* side of the op is deferred to wait()).
-        wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
-        wb_keys = jnp.where(wb, alloc.evicted_key, -1)
-        # Only the dirty-evicted lanes' bytes ever reach storage (the DMA
-        # drops key -1 lanes), so the line gather is masked by ``wb`` and
-        # skipped outright when the wavefront evicted nothing dirty — the
-        # warm-cache steady state never touches the line store here.
-        ev_lines = jax.lax.cond(
-            jnp.any(wb),
-            lambda: cache2.data[jnp.where(wb, alloc.slot, 0)],
-            lambda: jnp.zeros((ukeys.shape[0], cache2.line_elems),
-                              cache2.data.dtype))
+        with jax.named_scope("write_back"):
+            wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
+            wb_keys = jnp.where(wb, alloc.evicted_key, -1)
+            # Only the dirty-evicted lanes' bytes ever reach storage (the DMA
+            # drops key -1 lanes), so the line gather is masked by ``wb`` and
+            # skipped outright when the wavefront evicted nothing dirty — the
+            # warm-cache steady state never touches the line store here.
+            ev_lines = jax.lax.cond(
+                jnp.any(wb),
+                lambda: cache2.data[jnp.where(wb, alloc.slot, 0)],
+                lambda: jnp.zeros((ukeys.shape[0], cache2.line_elems),
+                                  cache2.data.dtype))
 
         # 4b) readahead (read ops): extrapolate the wavefront's stride and
         #     speculatively claim the predicted lines — enqueued in the
         #     low-priority lane, fetched at wait.
-        cfg = self.prefetch_cfg
-        ra_on = kind == "read" and cfg.enabled and cfg.window > 0
-        ra_keys_tok = None
-        if ra_on:
-            ra_cand = readahead_keys(
-                ukeys, uvalid, window=cfg.window, num_blocks=self.num_blocks,
-                min_support=cfg.min_support, max_stride=cfg.max_stride,
-                raw_keys=blk, raw_valid=valid)
-            # Never speculatively re-fetch a line this wavefront just
-            # evicted: on the sim backend the fetch (pure_callback) is not
-            # ordered against the dirty write-back (io_callback), so it
-            # could observe the pre-write-back bytes — and re-fetching a
-            # just-evicted line is pure thrash regardless of backend.
-            evk = jnp.where(alloc.ok & (alloc.evicted_key >= 0),
-                            alloc.evicted_key, -2)
-            not_evicted = ~jnp.any(
-                ra_cand[:, None] == evk[None, :], axis=1)
-            # Fused probe + speculative allocate for the predicted lines
-            # (probe hits are NOT protected here — only the demand
-            # wavefront's hit and granted slots are, as before).
-            cache2, _, ra_alloc = C.probe_allocate(
-                cache2, ra_cand, ra_cand >= 0, alloc_mask=not_evicted,
-                protect_slots=jnp.concatenate([pr.slot, alloc.slot]),
-                protect_hits=False, speculative=True,
-                tenant=ctx.tenant, way_lo=ctx.way_lo, way_hi=ctx.way_hi,
-                impl=self.kernel_impl)
-            ra_keys = jnp.where(ra_alloc.ok, ra_cand, -1)
-            ra_rows = jnp.where(ra_alloc.ok, ra_alloc.slot, 0)
-            ra_ev_lines = cache2.data[ra_rows]
-            ra_wb = ra_alloc.ok & ra_alloc.evicted_dirty \
-                & (ra_alloc.evicted_key >= 0)
-            ra_wb_keys = jnp.where(ra_wb, ra_alloc.evicted_key, -1)
-            cache2 = C.mark_inflight(
-                cache2, jnp.where(ra_alloc.ok, ra_alloc.slot, -1))
-            ra_keys_tok = ra_keys
+        with jax.named_scope("readahead"):
+            cfg = self.prefetch_cfg
+            ra_on = kind == "read" and cfg.enabled and cfg.window > 0
+            ra_keys_tok = None
+            if ra_on:
+                ra_cand = readahead_keys(
+                    ukeys, uvalid, window=cfg.window,
+                    num_blocks=self.num_blocks,
+                    min_support=cfg.min_support, max_stride=cfg.max_stride,
+                    raw_keys=blk, raw_valid=valid)
+                # Never speculatively re-fetch a line this wavefront just
+                # evicted: on the sim backend the fetch (pure_callback) is not
+                # ordered against the dirty write-back (io_callback), so it
+                # could observe the pre-write-back bytes — and re-fetching a
+                # just-evicted line is pure thrash regardless of backend.
+                evk = jnp.where(alloc.ok & (alloc.evicted_key >= 0),
+                                alloc.evicted_key, -2)
+                not_evicted = ~jnp.any(
+                    ra_cand[:, None] == evk[None, :], axis=1)
+                # Fused probe + speculative allocate for the predicted lines
+                # (probe hits are NOT protected here — only the demand
+                # wavefront's hit and granted slots are, as before).
+                cache2, _, ra_alloc = C.probe_allocate(
+                    cache2, ra_cand, ra_cand >= 0, alloc_mask=not_evicted,
+                    protect_slots=jnp.concatenate([pr.slot, alloc.slot]),
+                    protect_hits=False, speculative=True,
+                    tenant=ctx.tenant, way_lo=ctx.way_lo, way_hi=ctx.way_hi,
+                    impl=self.kernel_impl)
+                ra_keys = jnp.where(ra_alloc.ok, ra_cand, -1)
+                ra_rows = jnp.where(ra_alloc.ok, ra_alloc.slot, 0)
+                ra_ev_lines = cache2.data[ra_rows]
+                ra_wb = ra_alloc.ok & ra_alloc.evicted_dirty \
+                    & (ra_alloc.evicted_key >= 0)
+                ra_wb_keys = jnp.where(ra_wb, ra_alloc.evicted_key, -1)
+                cache2 = C.mark_inflight(
+                    cache2, jnp.where(ra_alloc.ok, ra_alloc.slot, -1))
+                ra_keys_tok = ra_keys
 
         # 5) enqueue reads + write-backs into the SQ rings; ring doorbells.
         #    Readahead goes last and in the low-priority lane: it is the
@@ -810,136 +830,143 @@ class BamArray:
         #    The rings are NOT drained here — that is wait()'s job, so
         #    commands from several outstanding tokens genuinely coexist and
         #    the queues fill toward the Little's-law depth.
-        read_keys = jnp.where(miss, ukeys, -1)
-        segs = [(read_keys, alloc.slot, None, None, Q.PRIO_DEMAND),
-                (wb_keys, None, jnp.ones_like(wb), None, Q.PRIO_DEMAND)]
-        if kind == "write":
-            # Bypassed lines (no slot granted) are written through at wait;
-            # their commands ride the rings like every other write.
-            byp = miss & ~alloc.ok
-            bt_keys = jnp.where(byp, ukeys, -1)
-            segs.append((bt_keys, None, jnp.ones_like(byp), None,
-                         Q.PRIO_DEMAND))
-        if ra_on:
-            segs.append((ra_wb_keys, None, jnp.ones_like(ra_wb), None,
-                         Q.PRIO_DEMAND))
-            segs.append((ra_keys, ra_alloc.slot, None, None,
-                         Q.PRIO_READAHEAD))
-        if self.fused_rounds:
-            # one fused pass: one combined scatter per SQ ring field, one
-            # QueueState rebuild (bit-identical to the sequential enqueues
-            # — the differential oracle pins it)
-            qs2, recs = Q.enqueue_segments(st.queues, segs,
-                                           tenant=ctx.tenant,
-                                           impl=self.kernel_impl)
-        else:
-            qs2, recs = st.queues, []
-            # static unroll: segs has trace-time-constant length (2-4)
-            for keys_s, dst_s, w_s, v_s, p_s in segs:  # bamlint: ignore[BAM104]
-                qs2, rec = Q.enqueue(qs2, keys_s, dst=dst_s, is_write=w_s,
-                                     valid=v_s, prio=p_s, tenant=ctx.tenant)
-                recs.append(rec)
-        it = iter(recs)
-        rec_r, rec_w = next(it), next(it)
-        n_doorbells = rec_r.n_doorbells + rec_w.n_doorbells
-        n_dropped = rec_r.n_dropped + rec_w.n_dropped
-        dev_reads_tok = device_histogram(ukeys, nd, miss, sb, fd)
-        dev_writes_tok = device_histogram(wb_keys, nd, stripe_blocks=sb,
-                                          failed_devices=fd)
-        drop_reads = device_histogram(read_keys, nd, ~rec_r.accepted, sb, fd)
-        drop_writes = device_histogram(wb_keys, nd, ~rec_w.accepted, sb, fd)
-        # Per-unique-row command tickets (the fault-hash counter) and the
-        # rows whose demand command the rings rejected: both feed the token
-        # so wait() can resolve completion status and callers can see
-        # back-pressure drops per lane instead of only as a global count.
-        ticket_tok = rec_r.ticket
-        drop_u = miss & ~rec_r.accepted
-        if kind == "write":
-            rec_bt = next(it)
-            n_doorbells = n_doorbells + rec_bt.n_doorbells
-            n_dropped = n_dropped + rec_bt.n_dropped
-            dev_writes_tok = dev_writes_tok + device_histogram(
-                bt_keys, nd, stripe_blocks=sb, failed_devices=fd)
-            drop_writes = drop_writes + device_histogram(
-                bt_keys, nd, ~rec_bt.accepted, sb, fd)
-            drop_u = drop_u | (byp & ~rec_bt.accepted)
-        ra_ticket_tok = None
-        if ra_on:
-            rec_rw, rec_ra = next(it), next(it)
-            n_doorbells = n_doorbells + rec_rw.n_doorbells + rec_ra.n_doorbells
-            n_dropped = n_dropped + rec_rw.n_dropped + rec_ra.n_dropped
-            dev_reads_tok = dev_reads_tok + device_histogram(
-                ra_keys, nd, stripe_blocks=sb, failed_devices=fd)
-            dev_writes_tok = dev_writes_tok + device_histogram(
-                ra_wb_keys, nd, stripe_blocks=sb, failed_devices=fd)
-            drop_reads = drop_reads + device_histogram(
-                ra_keys, nd, ~rec_ra.accepted, sb, fd)
-            drop_writes = drop_writes + device_histogram(
-                ra_wb_keys, nd, ~rec_rw.accepted, sb, fd)
-            ra_ticket_tok = rec_ra.ticket
-        depth_now = Q.in_flight(qs2)
-        depth_dev = Q.in_flight_per_device(qs2)
+        with jax.named_scope("enqueue"):
+            read_keys = jnp.where(miss, ukeys, -1)
+            segs = [(read_keys, alloc.slot, None, None, Q.PRIO_DEMAND),
+                    (wb_keys, None, jnp.ones_like(wb), None, Q.PRIO_DEMAND)]
+            if kind == "write":
+                # Bypassed lines (no slot granted) are written through at wait;
+                # their commands ride the rings like every other write.
+                byp = miss & ~alloc.ok
+                bt_keys = jnp.where(byp, ukeys, -1)
+                segs.append((bt_keys, None, jnp.ones_like(byp), None,
+                             Q.PRIO_DEMAND))
+            if ra_on:
+                segs.append((ra_wb_keys, None, jnp.ones_like(ra_wb), None,
+                             Q.PRIO_DEMAND))
+                segs.append((ra_keys, ra_alloc.slot, None, None,
+                             Q.PRIO_READAHEAD))
+            if self.fused_rounds:
+                # one fused pass: one combined scatter per SQ ring field, one
+                # QueueState rebuild (bit-identical to the sequential enqueues
+                # — the differential oracle pins it)
+                qs2, recs = Q.enqueue_segments(st.queues, segs,
+                                               tenant=ctx.tenant,
+                                               impl=self.kernel_impl)
+            else:
+                qs2, recs = st.queues, []
+                # static unroll: segs has trace-time-constant length (2-4)
+                for keys_s, dst_s, w_s, v_s, p_s in segs:  # bamlint: ignore[BAM104]
+                    qs2, rec = Q.enqueue(qs2, keys_s, dst=dst_s,
+                                         is_write=w_s, valid=v_s, prio=p_s,
+                                         tenant=ctx.tenant)
+                    recs.append(rec)
+            it = iter(recs)
+            rec_r, rec_w = next(it), next(it)
+            n_doorbells = rec_r.n_doorbells + rec_w.n_doorbells
+            n_dropped = rec_r.n_dropped + rec_w.n_dropped
+            dev_reads_tok = device_histogram(ukeys, nd, miss, sb, fd)
+            dev_writes_tok = device_histogram(wb_keys, nd, stripe_blocks=sb,
+                                              failed_devices=fd)
+            drop_reads = device_histogram(read_keys, nd, ~rec_r.accepted,
+                                          sb, fd)
+            drop_writes = device_histogram(wb_keys, nd, ~rec_w.accepted,
+                                           sb, fd)
+            # Per-unique-row command tickets (the fault-hash counter) and the
+            # rows whose demand command the rings rejected: both feed the token
+            # so wait() can resolve completion status and callers can see
+            # back-pressure drops per lane instead of only as a global count.
+            ticket_tok = rec_r.ticket
+            drop_u = miss & ~rec_r.accepted
+            if kind == "write":
+                rec_bt = next(it)
+                n_doorbells = n_doorbells + rec_bt.n_doorbells
+                n_dropped = n_dropped + rec_bt.n_dropped
+                dev_writes_tok = dev_writes_tok + device_histogram(
+                    bt_keys, nd, stripe_blocks=sb, failed_devices=fd)
+                drop_writes = drop_writes + device_histogram(
+                    bt_keys, nd, ~rec_bt.accepted, sb, fd)
+                drop_u = drop_u | (byp & ~rec_bt.accepted)
+            ra_ticket_tok = None
+            if ra_on:
+                rec_rw, rec_ra = next(it), next(it)
+                n_doorbells = (n_doorbells + rec_rw.n_doorbells
+                               + rec_ra.n_doorbells)
+                n_dropped = n_dropped + rec_rw.n_dropped + rec_ra.n_dropped
+                dev_reads_tok = dev_reads_tok + device_histogram(
+                    ra_keys, nd, stripe_blocks=sb, failed_devices=fd)
+                dev_writes_tok = dev_writes_tok + device_histogram(
+                    ra_wb_keys, nd, stripe_blocks=sb, failed_devices=fd)
+                drop_reads = drop_reads + device_histogram(
+                    ra_keys, nd, ~rec_ra.accepted, sb, fd)
+                drop_writes = drop_writes + device_histogram(
+                    ra_wb_keys, nd, ~rec_rw.accepted, sb, fd)
+                ra_ticket_tok = rec_ra.ticket
+            depth_now = Q.in_flight(qs2)
+            depth_dev = Q.in_flight_per_device(qs2)
 
         # 6) persist evicted dirty lines (write DMA happens at submit; the
         #    fetch DMA is deferred to wait).
-        store = self._store(st)
-        new_storage = st.storage
-        if self.storage is None:                    # in-graph backend
-            new_storage = store.write_blocks(wb_keys, ev_lines)
-            if ra_on:
-                new_storage = new_storage.write_blocks(ra_wb_keys,
-                                                       ra_ev_lines)
-        else:
-            self.storage.write_blocks(wb_keys, ev_lines)
-            if ra_on:
-                self.storage.write_blocks(ra_wb_keys, ra_ev_lines)
+        with jax.named_scope("write_back"):
+            store = self._store(st)
+            new_storage = st.storage
+            if self.storage is None:                    # in-graph backend
+                new_storage = store.write_blocks(wb_keys, ev_lines)
+                if ra_on:
+                    new_storage = new_storage.write_blocks(ra_wb_keys,
+                                                           ra_ev_lines)
+            else:
+                self.storage.write_blocks(wb_keys, ev_lines)
+                if ra_on:
+                    self.storage.write_blocks(ra_wb_keys, ra_ev_lines)
 
         # 7) submission-side metrics.  Device busy time, bytes fetched and
         #    the per-device charge histograms are wait-side (they belong to
         #    the drain); everything the submission itself decides is here.
-        n_valid = jnp.sum(valid.astype(jnp.int32))
-        n_miss = jnp.sum(miss.astype(jnp.int32))
-        n_wb = jnp.sum(wb.astype(jnp.int32))
-        n_ra = jnp.zeros((), jnp.int32)
-        if ra_on:
-            n_ra = jnp.sum(ra_alloc.ok.astype(jnp.int32))
-            n_wb = n_wb + jnp.sum(ra_wb.astype(jnp.int32))
-        if kind == "write":
-            n_wb = n_wb + jnp.sum(byp.astype(jnp.int32))
-        itemsize = jnp.dtype(self.dtype).itemsize
-        tok_new = jnp.any(valid).astype(mt.requests.dtype)
-        window_now = (mt.tokens_in_flight + tok_new).astype(jnp.int32)
-        metrics = dataclasses.replace(
-            mt,
-            requests=mt.requests + n_valid,
-            bytes_requested=mt.bytes_requested + n_valid * itemsize,
-            hits=mt.hits + n_hit,
-            misses=mt.misses + n_miss,
-            write_ops=mt.write_ops + n_wb,
-            bytes_to_storage=mt.bytes_to_storage + n_wb * self.block_bytes,
-            doorbells=mt.doorbells + n_doorbells,
-            dropped=mt.dropped + n_dropped,
-            prefetch_issued=mt.prefetch_issued + n_ra,
-            prefetch_hits=mt.prefetch_hits + n_pref_hit,
-            max_queue_depth=jnp.maximum(mt.max_queue_depth,
-                                        depth_now.astype(jnp.int32)),
-            dev_max_depth=jnp.maximum(mt.dev_max_depth,
-                                      depth_dev.astype(jnp.int32)),
-            tokens_submitted=mt.tokens_submitted + tok_new,
-            tokens_in_flight=mt.tokens_in_flight + tok_new,
-            cross_op_coalesced=mt.cross_op_coalesced + n_cross,
-            max_tokens_in_flight=jnp.maximum(mt.max_tokens_in_flight,
-                                             window_now),
-        )
-        token = IOToken(
-            kind=kind, valid=valid, off=off, inverse=co.inverse_idx,
-            ukeys=ukeys, pin_slots=pin_slots,
-            values=req.values if kind == "write" else None,
-            ra_keys=ra_keys_tok,
-            dev_reads=dev_reads_tok, dev_writes=dev_writes_tok,
-            drop_dev_reads=drop_reads, drop_dev_writes=drop_writes,
-            ticket=ticket_tok, ra_ticket=ra_ticket_tok,
-            dropped_mask=valid & drop_u[co.inverse_idx])
+        with jax.named_scope("accounting"):
+            n_valid = jnp.sum(valid.astype(jnp.int32))
+            n_miss = jnp.sum(miss.astype(jnp.int32))
+            n_wb = jnp.sum(wb.astype(jnp.int32))
+            n_ra = jnp.zeros((), jnp.int32)
+            if ra_on:
+                n_ra = jnp.sum(ra_alloc.ok.astype(jnp.int32))
+                n_wb = n_wb + jnp.sum(ra_wb.astype(jnp.int32))
+            if kind == "write":
+                n_wb = n_wb + jnp.sum(byp.astype(jnp.int32))
+            itemsize = jnp.dtype(self.dtype).itemsize
+            tok_new = jnp.any(valid).astype(mt.requests.dtype)
+            window_now = (mt.tokens_in_flight + tok_new).astype(jnp.int32)
+            metrics = dataclasses.replace(
+                mt,
+                requests=mt.requests + n_valid,
+                bytes_requested=mt.bytes_requested + n_valid * itemsize,
+                hits=mt.hits + n_hit,
+                misses=mt.misses + n_miss,
+                write_ops=mt.write_ops + n_wb,
+                bytes_to_storage=mt.bytes_to_storage + n_wb * self.block_bytes,
+                doorbells=mt.doorbells + n_doorbells,
+                dropped=mt.dropped + n_dropped,
+                prefetch_issued=mt.prefetch_issued + n_ra,
+                prefetch_hits=mt.prefetch_hits + n_pref_hit,
+                max_queue_depth=jnp.maximum(mt.max_queue_depth,
+                                            depth_now.astype(jnp.int32)),
+                dev_max_depth=jnp.maximum(mt.dev_max_depth,
+                                          depth_dev.astype(jnp.int32)),
+                tokens_submitted=mt.tokens_submitted + tok_new,
+                tokens_in_flight=mt.tokens_in_flight + tok_new,
+                cross_op_coalesced=mt.cross_op_coalesced + n_cross,
+                max_tokens_in_flight=jnp.maximum(mt.max_tokens_in_flight,
+                                                 window_now),
+            )
+            token = IOToken(
+                kind=kind, valid=valid, off=off, inverse=co.inverse_idx,
+                ukeys=ukeys, pin_slots=pin_slots,
+                values=req.values if kind == "write" else None,
+                ra_keys=ra_keys_tok,
+                dev_reads=dev_reads_tok, dev_writes=dev_writes_tok,
+                drop_dev_reads=drop_reads, drop_dev_writes=drop_writes,
+                ticket=ticket_tok, ra_ticket=ra_ticket_tok,
+                dropped_mask=valid & drop_u[co.inverse_idx])
         return BamState(cache=cache2, queues=qs2, metrics=metrics,
                         storage=new_storage), token
 
@@ -983,75 +1010,81 @@ class BamArray:
         # before).  A hint landing on a line some pending token is already
         # fetching is a cross-op coalesce too: nothing to claim, nothing
         # to enqueue.
-        cache1, pr, alloc = C.probe_allocate(
-            st.cache, ukeys, uvalid, speculative=True, tenant=ctx.tenant,
-            way_lo=ctx.way_lo, way_hi=ctx.way_hi, impl=self.kernel_impl)
-        n_cross = jnp.sum(pr.inflight.astype(jnp.int32))
-        ev_rows = jnp.where(alloc.ok, alloc.slot, 0)
-        ev_lines = cache1.data[ev_rows]
-        wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
-        wb_keys = jnp.where(wb, alloc.evicted_key, -1)
-        keys = jnp.where(alloc.ok, ukeys, -1)
-        cache1 = C.mark_inflight(cache1,
-                                 jnp.where(alloc.ok, alloc.slot, -1))
+        with jax.named_scope("probe_allocate"):
+            cache1, pr, alloc = C.probe_allocate(
+                st.cache, ukeys, uvalid, speculative=True, tenant=ctx.tenant,
+                way_lo=ctx.way_lo, way_hi=ctx.way_hi, impl=self.kernel_impl)
+            n_cross = jnp.sum(pr.inflight.astype(jnp.int32))
+            ev_rows = jnp.where(alloc.ok, alloc.slot, 0)
+            ev_lines = cache1.data[ev_rows]
+            wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
+            wb_keys = jnp.where(wb, alloc.evicted_key, -1)
+            keys = jnp.where(alloc.ok, ukeys, -1)
+            cache1 = C.mark_inflight(cache1,
+                                     jnp.where(alloc.ok, alloc.slot, -1))
 
-        segs = [(wb_keys, None, jnp.ones_like(wb), None, Q.PRIO_DEMAND),
-                (keys, alloc.slot, None, None, Q.PRIO_READAHEAD)]
-        if self.fused_rounds:
-            qs2, (rec_w, rec_r) = Q.enqueue_segments(
-                st.queues, segs, tenant=ctx.tenant, impl=self.kernel_impl)
-        else:
-            qs2, rec_w = Q.enqueue(st.queues, wb_keys,
-                                   is_write=jnp.ones_like(wb),
-                                   tenant=ctx.tenant)
-            qs2, rec_r = Q.enqueue(qs2, keys, dst=alloc.slot,
-                                   prio=Q.PRIO_READAHEAD, tenant=ctx.tenant)
-        depth_now = Q.in_flight(qs2)
-        depth_dev = Q.in_flight_per_device(qs2)
+        with jax.named_scope("enqueue"):
+            segs = [(wb_keys, None, jnp.ones_like(wb), None, Q.PRIO_DEMAND),
+                    (keys, alloc.slot, None, None, Q.PRIO_READAHEAD)]
+            if self.fused_rounds:
+                qs2, (rec_w, rec_r) = Q.enqueue_segments(
+                    st.queues, segs, tenant=ctx.tenant, impl=self.kernel_impl)
+            else:
+                qs2, rec_w = Q.enqueue(st.queues, wb_keys,
+                                       is_write=jnp.ones_like(wb),
+                                       tenant=ctx.tenant)
+                qs2, rec_r = Q.enqueue(qs2, keys, dst=alloc.slot,
+                                       prio=Q.PRIO_READAHEAD,
+                                       tenant=ctx.tenant)
+            depth_now = Q.in_flight(qs2)
+            depth_dev = Q.in_flight_per_device(qs2)
 
-        store = self._store(st)
-        new_storage = st.storage
-        if self.storage is None:                    # in-graph backend
-            new_storage = store.write_blocks(wb_keys, ev_lines)
-        else:
-            self.storage.write_blocks(wb_keys, ev_lines)
+        with jax.named_scope("write_back"):
+            store = self._store(st)
+            new_storage = st.storage
+            if self.storage is None:                    # in-graph backend
+                new_storage = store.write_blocks(wb_keys, ev_lines)
+            else:
+                self.storage.write_blocks(wb_keys, ev_lines)
 
-        n_ra = jnp.sum(alloc.ok.astype(jnp.int32))
-        n_wb = jnp.sum(wb.astype(jnp.int32))
-        dev_reads_tok = device_histogram(keys, nd, stripe_blocks=sb,
-                                         failed_devices=fd)
-        dev_writes_tok = device_histogram(wb_keys, nd, stripe_blocks=sb,
-                                          failed_devices=fd)
-        drop_reads = device_histogram(keys, nd, ~rec_r.accepted, sb, fd)
-        drop_writes = device_histogram(wb_keys, nd, ~rec_w.accepted, sb, fd)
-        tok_new = jnp.any(valid).astype(mt.requests.dtype)
-        window_now = (mt.tokens_in_flight + tok_new).astype(jnp.int32)
-        metrics = dataclasses.replace(
-            mt,
-            write_ops=mt.write_ops + n_wb,
-            bytes_to_storage=mt.bytes_to_storage + n_wb * self.block_bytes,
-            doorbells=mt.doorbells + rec_r.n_doorbells + rec_w.n_doorbells,
-            dropped=mt.dropped + rec_r.n_dropped + rec_w.n_dropped,
-            prefetch_issued=mt.prefetch_issued + n_ra,
-            max_queue_depth=jnp.maximum(mt.max_queue_depth,
-                                        depth_now.astype(jnp.int32)),
-            dev_max_depth=jnp.maximum(mt.dev_max_depth,
-                                      depth_dev.astype(jnp.int32)),
-            tokens_submitted=mt.tokens_submitted + tok_new,
-            tokens_in_flight=mt.tokens_in_flight + tok_new,
-            cross_op_coalesced=mt.cross_op_coalesced + n_cross,
-            max_tokens_in_flight=jnp.maximum(mt.max_tokens_in_flight,
-                                             window_now),
-        )
-        token = IOToken(
-            kind="prefetch", valid=valid, off=off, inverse=co.inverse_idx,
-            ukeys=ukeys, pin_slots=jnp.full_like(ukeys, -1),
-            values=None, ra_keys=None,
-            dev_reads=dev_reads_tok, dev_writes=dev_writes_tok,
-            drop_dev_reads=drop_reads, drop_dev_writes=drop_writes,
-            ticket=rec_r.ticket, ra_ticket=None,
-            dropped_mask=valid & (alloc.ok
-                                  & ~rec_r.accepted)[co.inverse_idx])
+        with jax.named_scope("accounting"):
+            n_ra = jnp.sum(alloc.ok.astype(jnp.int32))
+            n_wb = jnp.sum(wb.astype(jnp.int32))
+            dev_reads_tok = device_histogram(keys, nd, stripe_blocks=sb,
+                                             failed_devices=fd)
+            dev_writes_tok = device_histogram(wb_keys, nd, stripe_blocks=sb,
+                                              failed_devices=fd)
+            drop_reads = device_histogram(keys, nd, ~rec_r.accepted, sb, fd)
+            drop_writes = device_histogram(wb_keys, nd, ~rec_w.accepted,
+                                           sb, fd)
+            tok_new = jnp.any(valid).astype(mt.requests.dtype)
+            window_now = (mt.tokens_in_flight + tok_new).astype(jnp.int32)
+            metrics = dataclasses.replace(
+                mt,
+                write_ops=mt.write_ops + n_wb,
+                bytes_to_storage=mt.bytes_to_storage + n_wb * self.block_bytes,
+                doorbells=mt.doorbells + rec_r.n_doorbells + rec_w.n_doorbells,
+                dropped=mt.dropped + rec_r.n_dropped + rec_w.n_dropped,
+                prefetch_issued=mt.prefetch_issued + n_ra,
+                max_queue_depth=jnp.maximum(mt.max_queue_depth,
+                                            depth_now.astype(jnp.int32)),
+                dev_max_depth=jnp.maximum(mt.dev_max_depth,
+                                          depth_dev.astype(jnp.int32)),
+                tokens_submitted=mt.tokens_submitted + tok_new,
+                tokens_in_flight=mt.tokens_in_flight + tok_new,
+                cross_op_coalesced=mt.cross_op_coalesced + n_cross,
+                max_tokens_in_flight=jnp.maximum(mt.max_tokens_in_flight,
+                                                 window_now),
+            )
+            token = IOToken(
+                kind="prefetch", valid=valid, off=off, inverse=co.inverse_idx,
+                ukeys=ukeys, pin_slots=jnp.full_like(ukeys, -1),
+                values=None, ra_keys=None,
+                dev_reads=dev_reads_tok, dev_writes=dev_writes_tok,
+                drop_dev_reads=drop_reads, drop_dev_writes=drop_writes,
+                ticket=rec_r.ticket, ra_ticket=None,
+                dropped_mask=valid & (alloc.ok
+                                      & ~rec_r.accepted)[co.inverse_idx])
         return BamState(cache=cache1, queues=qs2, metrics=metrics,
                         storage=new_storage), token
 
@@ -1147,69 +1180,71 @@ class BamArray:
         #    the WFQ arbitration sort and the per-command materialisation
         #    are skipped (BamRuntime.drain keeps service_all — it *is* the
         #    observable arbitration order).
-        fstats = None                       # fault accounting for this drain
-        if self.defer_drain:
-            qs2 = st.queues
-            reads_charge = token.dev_reads
-            writes_charge = token.dev_writes
-            if fault.enabled:
-                # Deferred mode never drains here; account this token's
-                # OWN commands from its ticket stamps (write-backs carry
-                # no token ticket — their errors surface at the round
-                # drain, not in per-token metrics).
-                fstats = self._token_fault_stats(token)
-        elif self.fused_rounds:
-            qs2, dr = Q.drain_accounting(
-                st.queues, impl=self.kernel_impl,
-                fault=fault if fault.enabled else None)
-            reads_charge = dr.reads_dev + token.drop_dev_reads
-            writes_charge = dr.writes_dev + token.drop_dev_writes
-            if fault.enabled:
-                fstats = dict(err_reads=dr.err_reads_dev,
-                              err_writes=dr.err_writes_dev,
-                              retry_reads=dr.retry_reads_dev,
-                              retry_writes=dr.retry_writes_dev,
-                              transient=dr.transient_errors)
-        else:
-            qs2, comps = Q.service_all(
-                st.queues, fault=fault if fault.enabled else None)
-            cvalid = comps.valid
-            reads_charge = device_histogram(
-                comps.keys, nd, cvalid & ~comps.is_write, sb, fd) \
-                + token.drop_dev_reads
-            writes_charge = device_histogram(
-                comps.keys, nd, cvalid & comps.is_write, sb, fd) \
-                + token.drop_dev_writes
-            if fault.enabled:
-                fstats = dict(err_reads=comps.err_reads_dev,
-                              err_writes=comps.err_writes_dev,
-                              retry_reads=comps.retry_reads_dev,
-                              retry_writes=comps.retry_writes_dev,
-                              transient=comps.transient)
+        with jax.named_scope("drain"):
+            fstats = None                   # fault accounting for this drain
+            if self.defer_drain:
+                qs2 = st.queues
+                reads_charge = token.dev_reads
+                writes_charge = token.dev_writes
+                if fault.enabled:
+                    # Deferred mode never drains here; account this token's
+                    # OWN commands from its ticket stamps (write-backs carry
+                    # no token ticket — their errors surface at the round
+                    # drain, not in per-token metrics).
+                    fstats = self._token_fault_stats(token)
+            elif self.fused_rounds:
+                qs2, dr = Q.drain_accounting(
+                    st.queues, impl=self.kernel_impl,
+                    fault=fault if fault.enabled else None)
+                reads_charge = dr.reads_dev + token.drop_dev_reads
+                writes_charge = dr.writes_dev + token.drop_dev_writes
+                if fault.enabled:
+                    fstats = dict(err_reads=dr.err_reads_dev,
+                                  err_writes=dr.err_writes_dev,
+                                  retry_reads=dr.retry_reads_dev,
+                                  retry_writes=dr.retry_writes_dev,
+                                  transient=dr.transient_errors)
+            else:
+                qs2, comps = Q.service_all(
+                    st.queues, fault=fault if fault.enabled else None)
+                cvalid = comps.valid
+                reads_charge = device_histogram(
+                    comps.keys, nd, cvalid & ~comps.is_write, sb, fd) \
+                    + token.drop_dev_reads
+                writes_charge = device_histogram(
+                    comps.keys, nd, cvalid & comps.is_write, sb, fd) \
+                    + token.drop_dev_writes
+                if fault.enabled:
+                    fstats = dict(err_reads=comps.err_reads_dev,
+                                  err_writes=comps.err_writes_dev,
+                                  retry_reads=comps.retry_reads_dev,
+                                  retry_writes=comps.retry_writes_dev,
+                                  transient=comps.transient)
 
         # 2) fresh probe: lines this token submitted may since have been
         #    filled by another token's wait (cross-op coalescing), written
         #    to, or — for unpinned speculative lines — evicted.
-        pr2 = C.probe(st.cache, ukeys, uvalid, tenant=ctx.tenant,
-                      impl=self.kernel_impl)
-        pend = pr2.hit & pr2.inflight              # resident, fill pending
-        # Resolve this token's command fates from the (device, ticket)
-        # stamps — the same pure function the drain accounting uses, so
-        # wait and drain can never disagree about which commands failed.
-        failed_u = jnp.zeros(ukeys.shape, bool)
-        ok_u = jnp.ones(ukeys.shape, bool)
-        if fault.enabled:
-            dev_u = device_of_block(ukeys, nd, sb, fd)
-            ok_u, _, _ = fault.command_status(dev_u, token.ticket)
-            failed_u = uvalid & ~ok_u
-        if token.kind == "prefetch":
-            # only materialise lines still awaiting their speculative fill
-            need = pend & ~failed_u
-        else:
-            # fetch everything not gatherable from the cache: still-pending
-            # grants plus bypassed keys (read/write-through) — minus rows
-            # whose own command errored: a failed fetch moves no data.
-            need = uvalid & (~pr2.hit | pend) & ~failed_u
+        with jax.named_scope("probe"):
+            pr2 = C.probe(st.cache, ukeys, uvalid, tenant=ctx.tenant,
+                          impl=self.kernel_impl)
+            pend = pr2.hit & pr2.inflight              # resident, fill pending
+            # Resolve this token's command fates from the (device, ticket)
+            # stamps — the same pure function the drain accounting uses, so
+            # wait and drain can never disagree about which commands failed.
+            failed_u = jnp.zeros(ukeys.shape, bool)
+            ok_u = jnp.ones(ukeys.shape, bool)
+            if fault.enabled:
+                dev_u = device_of_block(ukeys, nd, sb, fd)
+                ok_u, _, _ = fault.command_status(dev_u, token.ticket)
+                failed_u = uvalid & ~ok_u
+            if token.kind == "prefetch":
+                # only materialise lines still awaiting their speculative fill
+                need = pend & ~failed_u
+            else:
+                # fetch everything not gatherable from the cache: still-pending
+                # grants plus bypassed keys (read/write-through) — minus rows
+                # whose own command errored: a failed fetch moves no data.
+                need = uvalid & (~pr2.hit | pend) & ~failed_u
 
         # 3) the deferred fetch DMA + completion fill.  Filling only lines
         #    that are *still* in flight makes completion idempotent across
@@ -1217,135 +1252,142 @@ class BamArray:
         #    resident line and never clobber newer data with a re-fetch.
         #    Failed commands invalidate their pending line instead of
         #    filling it (never garbage-filled, never left in-flight).
-        store = self._store(st)
-        lines = self._fetch_gated(store, jnp.where(need, ukeys, -1), need)
-        if fault.enabled:
-            cache1 = C.fill_complete_status(st.cache, pr2.slot, pend, ok_u,
-                                            lines)
-        elif self.fused_rounds:
-            cache1 = C.fill_complete(st.cache, pr2.slot, pend, lines)
-        else:
-            cache1 = C.fill(st.cache, pr2.slot, pend, lines)
-            cache1 = C.clear_inflight(cache1,
-                                      jnp.where(pend, pr2.slot, -1))
-        n_fetch = jnp.sum(need.astype(jnp.int32))
-        new_storage = st.storage
+        with jax.named_scope("fetch"):
+            store = self._store(st)
+            lines = self._fetch_gated(store, jnp.where(need, ukeys, -1), need)
+        with jax.named_scope("fill"):
+            if fault.enabled:
+                cache1 = C.fill_complete_status(st.cache, pr2.slot, pend, ok_u,
+                                                lines)
+            elif self.fused_rounds:
+                cache1 = C.fill_complete(st.cache, pr2.slot, pend, lines)
+            else:
+                cache1 = C.fill(st.cache, pr2.slot, pend, lines)
+                cache1 = C.clear_inflight(cache1,
+                                          jnp.where(pend, pr2.slot, -1))
+            n_fetch = jnp.sum(need.astype(jnp.int32))
+            new_storage = st.storage
 
         # 3b) stride-readahead lines issued by this token's submit.
-        if token.ra_keys is not None:
-            ra = token.ra_keys
-            ra_pr = C.probe(cache1, ra, ra >= 0, tenant=ctx.tenant,
-                            impl=self.kernel_impl)
-            ra_pend = ra_pr.hit & ra_pr.inflight
-            ra_need = ra_pend
-            ra_ok = jnp.ones(ra.shape, bool)
-            if fault.enabled and token.ra_ticket is not None:
-                dev_ra = device_of_block(ra, nd, sb, fd)
-                ra_ok, _, _ = fault.command_status(dev_ra, token.ra_ticket)
-                # a failed speculative fetch degrades silently: the line
-                # is invalidated, no lane errors (nothing demanded it yet)
-                ra_need = ra_pend & ((ra < 0) | ra_ok)
-            lines_ra = self._fetch_gated(store, jnp.where(ra_need, ra, -1),
-                                         ra_need)
-            if fault.enabled:
-                cache1 = C.fill_complete_status(cache1, ra_pr.slot, ra_pend,
-                                                ra_ok, lines_ra)
-            elif self.fused_rounds:
-                cache1 = C.fill_complete(cache1, ra_pr.slot, ra_pend,
-                                         lines_ra)
-            else:
-                cache1 = C.fill(cache1, ra_pr.slot, ra_pend, lines_ra)
-                cache1 = C.clear_inflight(
-                    cache1, jnp.where(ra_pend, ra_pr.slot, -1))
-            n_fetch = n_fetch + jnp.sum(ra_need.astype(jnp.int32))
+        with jax.named_scope("readahead"):
+            if token.ra_keys is not None:
+                ra = token.ra_keys
+                ra_pr = C.probe(cache1, ra, ra >= 0, tenant=ctx.tenant,
+                                impl=self.kernel_impl)
+                ra_pend = ra_pr.hit & ra_pr.inflight
+                ra_need = ra_pend
+                ra_ok = jnp.ones(ra.shape, bool)
+                if fault.enabled and token.ra_ticket is not None:
+                    dev_ra = device_of_block(ra, nd, sb, fd)
+                    ra_ok, _, _ = fault.command_status(dev_ra, token.ra_ticket)
+                    # a failed speculative fetch degrades silently: the line
+                    # is invalidated, no lane errors (nothing demanded it yet)
+                    ra_need = ra_pend & ((ra < 0) | ra_ok)
+                lines_ra = self._fetch_gated(store, jnp.where(ra_need, ra, -1),
+                                             ra_need)
+                if fault.enabled:
+                    cache1 = C.fill_complete_status(cache1, ra_pr.slot,
+                                                    ra_pend, ra_ok, lines_ra)
+                elif self.fused_rounds:
+                    cache1 = C.fill_complete(cache1, ra_pr.slot, ra_pend,
+                                             lines_ra)
+                else:
+                    cache1 = C.fill(cache1, ra_pr.slot, ra_pend, lines_ra)
+                    cache1 = C.clear_inflight(
+                        cache1, jnp.where(ra_pend, ra_pr.slot, -1))
+                n_fetch = n_fetch + jnp.sum(ra_need.astype(jnp.int32))
 
         # 4) op-specific completion.
-        u = token.inverse
-        # Lanes whose unique line's own command errored: they read 0, their
-        # write payloads are withheld, and the caller sees them in the
-        # returned error_mask.  Constant False with the fault disabled.
-        err_lane = valid & failed_u[u]
-        if token.kind == "read":
-            # Gather the hit lanes through the kernel dispatch layer
-            # (Pallas scalar-prefetch line gather on TPU — the BlockSpec
-            # index map *is* the page-table walk; on the ref/XLA path the
-            # `off` column keeps it an element gather, not line-wide).
-            hit_u = pr2.hit[u]
-            hit_vals = K.gather_blocks(
-                cache1.data, jnp.where(hit_u, pr2.slot[u], -1), off=off,
-                impl=self.kernel_impl)
-            vals = jnp.where(hit_u, hit_vals, lines[u, off])
-            vals = jnp.where(valid, vals, 0).astype(self.dtype)
-            if fault.enabled:
-                # errored pend rows were invalidated, not filled — the
-                # stale probe still says hit, so mask their lanes to 0
-                vals = jnp.where(err_lane, jnp.zeros((), self.dtype), vals)
-            cache_f = cache1
-        elif token.kind == "write":
-            values = token.values
-            assert values is not None   # write tokens carry their payload
-            # scatter the new element values into resident lines...
-            # (errored lanes excluded: their line was invalidated, their
-            # write did not happen — no torn lines, no phantom dirty bits)
-            wr_lane = valid & ~err_lane if fault.enabled else valid
-            slot_r = jnp.where(pr2.hit[u], pr2.slot[u], -1)
-            in_cache = slot_r >= 0
-            rows = jnp.where(wr_lane & in_cache, slot_r, cache1.num_lines)
-            cols = jnp.where(wr_lane & in_cache, off, 0)
-            data = cache1.data.at[rows, cols].set(
-                values.astype(self.dtype), mode="drop")
-            cache_f = C._replace_data(cache1, data=data)
-            cache_f = C.mark_dirty(cache_f,
-                                   jnp.where(wr_lane & in_cache, slot_r, -1))
-            # ...and write through the lines that have no slot (bypass);
-            # a bypass row whose fetch errored wrote nothing (its RMW
-            # background line never arrived — skipping beats corrupting
-            # storage with a zero-filled line).
-            byp_u = (~pr2.hit[u]) & wr_lane
-            byp_rows = jnp.where(byp_u, u, lines.shape[0])
-            byp_lines = lines.at[byp_rows, jnp.where(byp_u, off, 0)].set(
-                values.astype(self.dtype), mode="drop")
-            bt_keys = jnp.where(uvalid & ~pr2.hit & ~failed_u, ukeys, -1)
-            if self.storage is None:
-                new_storage = new_storage.write_blocks(bt_keys, byp_lines)
-            else:
-                self.storage.write_blocks(bt_keys, byp_lines)
-            vals = jnp.where(valid, values, 0).astype(self.dtype)
-            if fault.enabled:
-                vals = jnp.where(err_lane, jnp.zeros((), self.dtype), vals)
-        else:                                       # prefetch: no values
-            vals = jnp.zeros(off.shape, self.dtype)
-            cache_f = cache1
+        with jax.named_scope("gather"):
+            u = token.inverse
+            # Lanes whose unique line's own command errored: they read 0, their
+            # write payloads are withheld, and the caller sees them in the
+            # returned error_mask.  Constant False with the fault disabled.
+            err_lane = valid & failed_u[u]
+            if token.kind == "read":
+                # Gather the hit lanes through the kernel dispatch layer
+                # (Pallas scalar-prefetch line gather on TPU — the BlockSpec
+                # index map *is* the page-table walk; on the ref/XLA path the
+                # `off` column keeps it an element gather, not line-wide).
+                hit_u = pr2.hit[u]
+                hit_vals = K.gather_blocks(
+                    cache1.data, jnp.where(hit_u, pr2.slot[u], -1), off=off,
+                    impl=self.kernel_impl)
+                vals = jnp.where(hit_u, hit_vals, lines[u, off])
+                vals = jnp.where(valid, vals, 0).astype(self.dtype)
+                if fault.enabled:
+                    # errored pend rows were invalidated, not filled — the
+                    # stale probe still says hit, so mask their lanes to 0
+                    vals = jnp.where(err_lane, jnp.zeros((), self.dtype), vals)
+                cache_f = cache1
+            elif token.kind == "write":
+                values = token.values
+                assert values is not None   # write tokens carry their payload
+                # scatter the new element values into resident lines...
+                # (errored lanes excluded: their line was invalidated, their
+                # write did not happen — no torn lines, no phantom dirty
+                # bits)
+                wr_lane = valid & ~err_lane if fault.enabled else valid
+                slot_r = jnp.where(pr2.hit[u], pr2.slot[u], -1)
+                in_cache = slot_r >= 0
+                rows = jnp.where(wr_lane & in_cache, slot_r, cache1.num_lines)
+                cols = jnp.where(wr_lane & in_cache, off, 0)
+                data = cache1.data.at[rows, cols].set(
+                    values.astype(self.dtype), mode="drop")
+                cache_f = C._replace_data(cache1, data=data)
+                cache_f = C.mark_dirty(
+                    cache_f, jnp.where(wr_lane & in_cache, slot_r, -1))
+                # ...and write through the lines that have no slot (bypass);
+                # a bypass row whose fetch errored wrote nothing (its RMW
+                # background line never arrived — skipping beats corrupting
+                # storage with a zero-filled line).
+                byp_u = (~pr2.hit[u]) & wr_lane
+                byp_rows = jnp.where(byp_u, u, lines.shape[0])
+                byp_lines = lines.at[byp_rows, jnp.where(byp_u, off, 0)].set(
+                    values.astype(self.dtype), mode="drop")
+                bt_keys = jnp.where(uvalid & ~pr2.hit & ~failed_u, ukeys, -1)
+                if self.storage is None:
+                    new_storage = new_storage.write_blocks(bt_keys, byp_lines)
+                else:
+                    self.storage.write_blocks(bt_keys, byp_lines)
+                vals = jnp.where(valid, values, 0).astype(self.dtype)
+                if fault.enabled:
+                    vals = jnp.where(err_lane, jnp.zeros((), self.dtype), vals)
+            else:                                       # prefetch: no values
+                vals = jnp.zeros(off.shape, self.dtype)
+                cache_f = cache1
 
         # 5) release the pins taken at submit.
-        cache_f = C.release(cache_f, token.pin_slots)
+        with jax.named_scope("release"):
+            cache_f = C.release(cache_f, token.pin_slots)
 
         # 6) completion-side metrics: bytes actually fetched + the drain's
         #    device busy time (max over channels gates the batch).
-        mt = st.metrics
-        tok_done = jnp.any(valid).astype(mt.requests.dtype)
-        fault_kw = {}
-        if fstats is not None:
-            n_err = fstats["err_reads"] + fstats["err_writes"]
-            n_retry = fstats["retry_reads"] + fstats["retry_writes"]
-            fault_kw = dict(
-                transient_errors=mt.transient_errors + fstats["transient"],
-                retries=mt.retries + jnp.sum(n_retry),
-                failed_commands=mt.failed_commands + jnp.sum(n_err),
-                degraded_reads=mt.degraded_reads
-                    + jnp.sum(err_lane.astype(jnp.int32)),
-                dev_errors=mt.dev_errors + n_err,
+        with jax.named_scope("accounting"):
+            mt = st.metrics
+            tok_done = jnp.any(valid).astype(mt.requests.dtype)
+            fault_kw = {}
+            if fstats is not None:
+                n_err = fstats["err_reads"] + fstats["err_writes"]
+                n_retry = fstats["retry_reads"] + fstats["retry_writes"]
+                fault_kw = dict(
+                    transient_errors=mt.transient_errors + fstats["transient"],
+                    retries=mt.retries + jnp.sum(n_retry),
+                    failed_commands=mt.failed_commands + jnp.sum(n_err),
+                    degraded_reads=mt.degraded_reads
+                        + jnp.sum(err_lane.astype(jnp.int32)),
+                    dev_errors=mt.dev_errors + n_err,
+                )
+            metrics = dataclasses.replace(
+                mt,
+                bytes_from_storage=mt.bytes_from_storage
+                    + n_fetch * self.block_bytes,
+                tokens_waited=mt.tokens_waited + tok_done,
+                tokens_in_flight=mt.tokens_in_flight - tok_done,
+                **self._charge_wait(mt, st.queues, reads_charge, writes_charge,
+                                    fstats=fstats),
+                **fault_kw,
             )
-        metrics = dataclasses.replace(
-            mt,
-            bytes_from_storage=mt.bytes_from_storage
-                + n_fetch * self.block_bytes,
-            tokens_waited=mt.tokens_waited + tok_done,
-            tokens_in_flight=mt.tokens_in_flight - tok_done,
-            **self._charge_wait(mt, st.queues, reads_charge, writes_charge,
-                                fstats=fstats),
-            **fault_kw,
-        )
         return BamState(cache=cache_f, queues=qs2, metrics=metrics,
                         storage=new_storage), vals, err_lane
 

@@ -15,17 +15,24 @@ Two interchangeable backends sit below the BaM queues/cache:
 
 Both expose ``fetch_blocks(keys) -> (n, block_elems)`` with sentinel keys
 (< 0) returning zeros, and a write path for the BaM write support.
+
+``SimStorage``'s two host callbacks are the request path's host half.  Each
+runs its body inside a profiler span (``bam.storage.fetch``,
+``bam.storage.write_back``, with the ``rows`` shipped and the ``live`` rows
+among them) and adds to plain host counters (:meth:`SimStorage.counters`).
+With the profiler off a span costs one inactive ``TraceAnnotation``.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+import threading
 from typing import Protocol
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback as _io_callback
+from jax.profiler import TraceAnnotation
 
 
 class BlockStore(Protocol):
@@ -37,14 +44,40 @@ class BlockStore(Protocol):
     def write_blocks(self, keys: jax.Array, lines: jax.Array) -> None: ...
 
 
+COUNTERS = ("fetch_calls", "fetch_rows", "fetch_live_rows",
+            "write_calls", "write_rows", "write_live_rows")
+
+
 @dataclasses.dataclass
 class SimStorage:
-    """Host-resident block store fetched via pure_callback (the 'SSD')."""
+    """Host-resident block store fetched via pure_callback (the 'SSD').
+
+    Counts what crosses the host boundary: callback calls, the rows each
+    ships, and the live rows (key >= 0) among them.  The callbacks may run
+    on several host threads, so the counts are taken under a lock.
+    """
 
     data: np.ndarray  # (num_blocks, block_elems)
+    _counts: dict = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(COUNTERS, 0),
+        repr=False, compare=False)
+    _lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
 
     def __post_init__(self):
         assert self.data.ndim == 2, "block store must be (num_blocks, block_elems)"
+
+    def counters(self) -> dict:
+        """Host-boundary counters since construction (``COUNTERS``)."""
+        with self._lock:
+            return dict(self._counts)
+
+    def _count(self, op: str, rows: int, live: int) -> None:
+        with self._lock:
+            c = self._counts
+            c[op + "_calls"] += 1
+            c[op + "_rows"] += rows
+            c[op + "_live_rows"] += live
 
     @property
     def num_blocks(self) -> int:
@@ -60,9 +93,14 @@ class SimStorage:
 
     def _host_fetch(self, keys: np.ndarray) -> np.ndarray:
         keys = np.asarray(keys)
-        safe = np.clip(keys, 0, self.num_blocks - 1)
-        out = self.data[safe]
-        out[keys < 0] = 0
+        dead = keys < 0
+        rows = int(keys.shape[0])
+        live = rows - int(np.count_nonzero(dead))
+        self._count("fetch", rows, live)
+        with TraceAnnotation("bam.storage.fetch", rows=rows, live=live):
+            safe = np.clip(keys, 0, self.num_blocks - 1)
+            out = self.data[safe]
+            out[dead] = 0
         return out
 
     def fetch_blocks(self, keys: jax.Array) -> jax.Array:
@@ -72,7 +110,11 @@ class SimStorage:
     def _host_write(self, keys: np.ndarray, lines: np.ndarray) -> np.ndarray:
         keys = np.asarray(keys)
         mask = keys >= 0
-        self.data[keys[mask]] = np.asarray(lines)[mask]
+        rows = int(keys.shape[0])
+        live = int(np.count_nonzero(mask))
+        self._count("write", rows, live)
+        with TraceAnnotation("bam.storage.write_back", rows=rows, live=live):
+            self.data[keys[mask]] = np.asarray(lines)[mask]
         return np.zeros((), np.int32)
 
     def write_blocks(self, keys: jax.Array, lines: jax.Array) -> jax.Array:

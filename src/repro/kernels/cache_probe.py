@@ -133,5 +133,6 @@ def cache_probe_pallas(tags: jax.Array, keys: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
+        name="cache_probe",
     )(*operands)
     return hit[:m, 0].astype(bool), slot[:m, 0]

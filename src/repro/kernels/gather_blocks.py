@@ -76,5 +76,6 @@ def gather_blocks_pallas(data: jax.Array, slots: jax.Array, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="gather_blocks",
     )(slots_p, data)
     return out[:n]

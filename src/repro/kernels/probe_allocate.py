@@ -202,6 +202,7 @@ def probe_allocate_pallas(tags, owner, refcount, dirty, speculative,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
+        name="probe_allocate",
     )(keys_p, am_p, prot_p,
       tags, owner, refcount.astype(jnp.int32),
       dirty.astype(jnp.int32), speculative.astype(jnp.int32),

@@ -1,7 +1,7 @@
 """Pass 1 — host-sync / retrace hazards inside jit-reachable code.
 
 These are the exact patterns behind the submit/wait control-path overhead
-the hot-path benchmark tracks (BENCH_hot_path.json): a hidden host sync
+the hot-path benchmark tracks (``benchmarks/hot_path.py``): a hidden host sync
 serializes the submission window; a shape-dependent Python branch or a
 per-call ``jax.jit`` wrapper forces a retrace/recompile on every op.
 
