@@ -184,7 +184,8 @@ class OpFamilyEntry:
 
     ``pure_all_hit`` marks executables whose all-hit fast path must stay
     free of *unconditional* host callbacks (the ``lax.cond``-gated fetch
-    contract) — the BAM503 rule only audits entries that claim it.
+    and write-back contract) — the BAM503 rule only audits entries that
+    claim it.
     """
 
     name: str
@@ -623,13 +624,14 @@ class BamArray:
         yield OpFamilyEntry(
             name="prefetch", get=lambda donate=False: self.prefetch_jit(),
             example_args=args_read, trace_keys=("prefetch",))
+        # submit's dirty write-back and wait's fetch DMA are lax.cond-gated:
+        # a round that evicts nothing dirty, or misses nothing, must never
+        # pay the host callback, so their executables claim pure_all_hit
+        # and BAM503 audits callback placement in them.
         yield OpFamilyEntry(
-            name="submit", donatable=True,
+            name="submit", donatable=True, pure_all_hit=True,
             get=lambda donate=False: self.submit_jit(donate=donate),
             example_args=args_req, trace_keys=("submit",))
-        # wait's fetch DMA is lax.cond-gated (PR 8): an all-hit round must
-        # never pay the host callback, so its executables claim
-        # pure_all_hit and BAM503 audits callback placement in them.
         yield OpFamilyEntry(
             name="wait", donatable=True, pure_all_hit=True,
             get=lambda donate=False: self.wait_jit(donate=donate,
@@ -773,15 +775,6 @@ class BamArray:
         with jax.named_scope("write_back"):
             wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
             wb_keys = jnp.where(wb, alloc.evicted_key, -1)
-            # Only the dirty-evicted lanes' bytes ever reach storage (the DMA
-            # drops key -1 lanes), so the line gather is masked by ``wb`` and
-            # skipped outright when the wavefront evicted nothing dirty — the
-            # warm-cache steady state never touches the line store here.
-            ev_lines = jax.lax.cond(
-                jnp.any(wb),
-                lambda: cache2.data[jnp.where(wb, alloc.slot, 0)],
-                lambda: jnp.zeros((ukeys.shape[0], cache2.line_elems),
-                                  cache2.data.dtype))
 
         # 4b) readahead (read ops): extrapolate the wavefront's stride and
         #     speculatively claim the predicted lines — enqueued in the
@@ -815,8 +808,6 @@ class BamArray:
                     tenant=ctx.tenant, way_lo=ctx.way_lo, way_hi=ctx.way_hi,
                     impl=self.kernel_impl)
                 ra_keys = jnp.where(ra_alloc.ok, ra_cand, -1)
-                ra_rows = jnp.where(ra_alloc.ok, ra_alloc.slot, 0)
-                ra_ev_lines = cache2.data[ra_rows]
                 ra_wb = ra_alloc.ok & ra_alloc.evicted_dirty \
                     & (ra_alloc.evicted_key >= 0)
                 ra_wb_keys = jnp.where(ra_wb, ra_alloc.evicted_key, -1)
@@ -906,19 +897,14 @@ class BamArray:
             depth_dev = Q.in_flight_per_device(qs2)
 
         # 6) persist evicted dirty lines (write DMA happens at submit; the
-        #    fetch DMA is deferred to wait).
+        #    fetch DMA is deferred to wait).  The grants above moved no
+        #    line's bytes, so the evicted lines are still in ``cache2``.
         with jax.named_scope("write_back"):
-            store = self._store(st)
-            new_storage = st.storage
-            if self.storage is None:                    # in-graph backend
-                new_storage = store.write_blocks(wb_keys, ev_lines)
-                if ra_on:
-                    new_storage = new_storage.write_blocks(ra_wb_keys,
-                                                           ra_ev_lines)
-            else:
-                self.storage.write_blocks(wb_keys, ev_lines)
-                if ra_on:
-                    self.storage.write_blocks(ra_wb_keys, ra_ev_lines)
+            new_storage = self._write_back_gated(
+                st.storage, cache2.data, alloc.slot, wb_keys, wb)
+            if ra_on:
+                new_storage = self._write_back_gated(
+                    new_storage, cache2.data, ra_alloc.slot, ra_wb_keys, ra_wb)
 
         # 7) submission-side metrics.  Device busy time, bytes fetched and
         #    the per-device charge histograms are wait-side (they belong to
@@ -1015,8 +1001,6 @@ class BamArray:
                 st.cache, ukeys, uvalid, speculative=True, tenant=ctx.tenant,
                 way_lo=ctx.way_lo, way_hi=ctx.way_hi, impl=self.kernel_impl)
             n_cross = jnp.sum(pr.inflight.astype(jnp.int32))
-            ev_rows = jnp.where(alloc.ok, alloc.slot, 0)
-            ev_lines = cache1.data[ev_rows]
             wb = alloc.ok & alloc.evicted_dirty & (alloc.evicted_key >= 0)
             wb_keys = jnp.where(wb, alloc.evicted_key, -1)
             keys = jnp.where(alloc.ok, ukeys, -1)
@@ -1040,12 +1024,8 @@ class BamArray:
             depth_dev = Q.in_flight_per_device(qs2)
 
         with jax.named_scope("write_back"):
-            store = self._store(st)
-            new_storage = st.storage
-            if self.storage is None:                    # in-graph backend
-                new_storage = store.write_blocks(wb_keys, ev_lines)
-            else:
-                self.storage.write_blocks(wb_keys, ev_lines)
+            new_storage = self._write_back_gated(
+                st.storage, cache1.data, alloc.slot, wb_keys, wb)
 
         with jax.named_scope("accounting"):
             n_ra = jnp.sum(alloc.ok.astype(jnp.int32))
@@ -1097,16 +1077,46 @@ class BamArray:
         zeros, so the skip branch is lane-wise value-identical to the
         fetch — and ``lax.cond`` executes exactly one branch at runtime,
         so a warm-cache wait never pays the ``pure_callback`` host
-        round-trip.  Only the sim backend's *fetch* may be gated: its
-        dirty write-back uses an **ordered** ``io_callback`` (not legal
-        under ``cond``), and the HBM backend's fetch is an in-graph gather
-        with nothing to elide.
+        round-trip.  Only the sim backend is gated: the HBM backend's
+        fetch is an in-graph gather with nothing to elide.  The dirty
+        write-back is gated the same way (:meth:`_write_back_gated`).
         """
         if not (self.fused_rounds and isinstance(store, SimStorage)):
             return store.fetch_blocks(keys)
         zeros = lambda k: jnp.zeros((k.shape[0], self.block_elems),
                                     store.dtype)
         return jax.lax.cond(jnp.any(need), store.fetch_blocks, zeros, keys)
+
+    def _write_back_gated(self, storage, data: jax.Array, slots: jax.Array,
+                          keys: jax.Array, need: jax.Array):
+        """Persist the dirty lines a submission evicted: ``data[slots]``
+        under ``keys`` on the lanes where ``need`` (``keys`` is ``-1``
+        elsewhere).  ``storage`` is the state's in-graph store (``None``
+        on the sim backend); returns it as the write leaves it.
+
+        Only the evicted lanes' bytes reach storage (a ``-1`` key is
+        dropped), so the line gather is masked by ``need`` and skipped
+        when no lane needs it.  On the fused sim path the gather *and*
+        the host write callback sit behind one ``lax.cond``, so a round
+        that evicted nothing dirty (all read-only traffic) makes no host
+        round trip.  JAX threads the ordered ``io_callback``'s token
+        through the conditional, so the writes that do run stay ordered.
+        The legacy path and the in-graph backend write every round.
+        """
+        any_need = jnp.any(need)
+        gather = lambda: data[jnp.where(need, slots, 0)]
+        if self.fused_rounds and isinstance(self.storage, SimStorage):
+            jax.lax.cond(any_need,
+                         lambda: self.storage.write_blocks(keys, gather()),
+                         lambda: jnp.zeros((), jnp.int32))
+            return storage
+        lines = jax.lax.cond(
+            any_need, gather,
+            lambda: jnp.zeros((keys.shape[0], data.shape[1]), data.dtype))
+        if self.storage is None:                        # in-graph backend
+            return storage.write_blocks(keys, lines)
+        self.storage.write_blocks(keys, lines)
+        return storage
 
     def wait(self, st: BamState, token: IOToken
              ) -> Tuple[BamState, jax.Array]:
@@ -2077,7 +2087,7 @@ class BamRuntime:
                 get=lambda donate=False, _n=name: self.read_jit(_n),
                 example_args=args_read, trace_keys=(f"read:{name}",))
             yield OpFamilyEntry(
-                name=f"submit:{name}", donatable=True,
+                name=f"submit:{name}", donatable=True, pure_all_hit=True,
                 get=lambda donate=False, _n=name:
                     self.submit_jit(_n, donate=donate),
                 example_args=args_req, trace_keys=(f"submit:{name}",))

@@ -16,10 +16,13 @@ Two interchangeable backends sit below the BaM queues/cache:
 Both expose ``fetch_blocks(keys) -> (n, block_elems)`` with sentinel keys
 (< 0) returning zeros, and a write path for the BaM write support.
 
-``SimStorage``'s two host callbacks are the request path's host half.  Each
-runs its body inside a profiler span (``bam.storage.fetch``,
-``bam.storage.write_back``, with the ``rows`` shipped and the ``live`` rows
-among them) and adds to plain host counters (:meth:`SimStorage.counters`).
+``SimStorage``'s two host callbacks are the request path's host half.  The
+fused request path calls each only when it has work: the fetch when a lane
+missed, the write-back when a dirty line was evicted (``BamArray``'s
+``_fetch_gated`` / ``_write_back_gated``).  Each runs its body inside a
+profiler span (``bam.storage.fetch``, ``bam.storage.write_back``, with the
+``rows`` shipped and the ``live`` rows among them) and adds to plain host
+counters (:meth:`SimStorage.counters`).
 With the profiler off a span costs one inactive ``TraceAnnotation``.
 """
 from __future__ import annotations

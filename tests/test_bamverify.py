@@ -144,6 +144,7 @@ def test_wait_claims_pure_all_hit_and_is_gated(family):
              for spec, _ in family["artifacts"]
              if spec.pure_all_hit]
     assert any(spec.op.startswith("wait") for spec, _ in waits)
+    assert any(spec.op.startswith("submit") for spec, _ in waits)
     for spec, stats in waits:
         # the callback exists in the executable but only behind the gate
         assert stats.custom_call_targets, spec.key
@@ -228,7 +229,8 @@ def test_bam505_flags_unbucketed_ragged_submits():
 
 def test_iter_op_family_covers_the_jit_surface():
     """The registry is the verifier's ground truth: it must enumerate the
-    ops, mark donatable ones, and claim purity only for wait."""
+    ops, mark donatable ones, and claim purity for submit and wait (both
+    gate their host callbacks)."""
     from tools.bamverify.lowering import canonical_array, canonical_runtime
 
     arr, _st = canonical_array()
@@ -237,7 +239,7 @@ def test_iter_op_family_covers_the_jit_surface():
                             "submit_wait", "bucketed_round"}
     assert entries["submit"].donatable and entries["wait"].donatable
     assert entries["wait"].pure_all_hit
-    assert not entries["submit"].pure_all_hit
+    assert entries["submit"].pure_all_hit
     assert entries["bucketed_round"].kind == "bucketed"
     assert set(entries["bucketed_round"].trace_keys) == {"submit", "wait"}
 
@@ -246,6 +248,7 @@ def test_iter_op_family_covers_the_jit_surface():
     for tenant in ("a", "b"):
         assert f"read:{tenant}" in rentries
         assert rentries[f"submit:{tenant}"].donatable
+        assert rentries[f"submit:{tenant}"].pure_all_hit
         assert rentries[f"wait:{tenant}"].pure_all_hit
 
 

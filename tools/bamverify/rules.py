@@ -36,7 +36,8 @@ RULES = {
               "(dtype creep that BAM303 could not see past lowering)",
     "BAM503": "host-callback custom-call executes unconditionally in an "
               "executable whose all-hit fast path must stay pure "
-              "(the lax.cond fetch gate was compiled away or bypassed)",
+              "(the lax.cond fetch or write-back gate was compiled away "
+              "or bypassed)",
     "BAM504": "serial scatter count above the recorded manifest baseline "
               "(a packed-scatter fusion regressed into per-field scatters)",
     "BAM505": "bucketed op compiled more executables than configured "
@@ -172,7 +173,8 @@ def check_artifact(spec: ArtifactSpec, hlo_text_or_stats,
             "host callback custom-call(s) "
             f"{stats.ungated_callbacks} execute unconditionally — the "
             "all-hit fast path would pay a host round-trip every round; "
-            "the fetch must stay behind its lax.cond gate"))
+            "the fetch and the write-back must stay behind their "
+            "lax.cond gates"))
     if baseline is not None and stats.scatters > int(baseline["scatters"]):
         out.append(Finding(
             "BAM504", spec.key,
