@@ -68,9 +68,9 @@ WARM_ROUNDS = scaled(8, 2)
 def _kv_tenant_data(rng):
     keys = np.arange(N_KEYS, dtype=np.int32)
     values = rng.standard_normal((N_KEYS, VALUE_ELEMS)).astype(np.float32)
-    table, store_vals, capacity = BamKVStore.build_table(
-        keys, values, capacity=2 * N_KEYS, probes=8)
-    return keys, values, jnp.asarray(table), store_vals, capacity
+    table, store_vals, capacity, probes = BamKVStore.build_table(
+        keys, values, capacity=2 * N_KEYS)
+    return keys, values, jnp.asarray(table), store_vals, capacity, probes
 
 
 def _kv_batches(rng, rounds):
@@ -140,7 +140,7 @@ def _hit_rate_window(summ_end, summ_start):
 def _run_config(isolation, *, with_neighbours, seed=0):
     """One full interleaved run; returns per-tenant window hit rates."""
     rng = np.random.default_rng(seed)
-    _, _, table, store_vals, capacity = _kv_tenant_data(rng)
+    _, _, table, store_vals, capacity, probes = _kv_tenant_data(rng)
     kv_spec = TenantSpec("kv", store_vals, block_elems=BLOCK_ELEMS,
                          ways=KV_WAYS)
     if with_neighbours:
@@ -163,7 +163,7 @@ def _run_config(isolation, *, with_neighbours, seed=0):
                                num_queues=8, queue_depth=1024,
                                isolation=isolation, drain="deferred")
     kv = BamKVStore(array=rt.array("kv"), capacity=capacity,
-                    value_elems=VALUE_ELEMS, probes=8)
+                    value_elems=VALUE_ELEMS, probes=probes)
 
     def kv_round(rst, keys):
         st = rt.tenant_view(rst, "kv")

@@ -13,7 +13,7 @@ Layers (bottom-up):
 """
 from repro.core.bam_array import (
     BamArray, BamKVStore, BamRuntime, BamState, IORequest, IOToken,
-    RuntimeState, TenantCtx, TenantSpec,
+    KVState, KVToken, RuntimeState, TenantCtx, TenantSpec,
 )
 from repro.core.cache import CacheState, make_cache
 from repro.core.coalescer import CoalesceResult, coalesce
@@ -35,7 +35,7 @@ from repro.core.storage import HBMStorage, SimStorage
 
 __all__ = [
     "BamArray", "BamKVStore", "BamRuntime", "BamState", "IORequest",
-    "IOToken", "RuntimeState",
+    "IOToken", "KVState", "KVToken", "RuntimeState",
     "TenantCtx", "TenantSpec", "CacheState", "make_cache",
     "CoalesceResult", "coalesce", "IOMetrics", "metrics_accumulate",
     "metrics_delta", "metrics_sum", "pipelined_bam_map",

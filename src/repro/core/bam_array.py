@@ -84,7 +84,8 @@ from repro.core.ssd import (ArrayOfSSDs, INTEL_OPTANE_P5800X,
 from repro.core.storage import HBMStorage, SimStorage
 from repro.utils import pad_to, pytree_dataclass, round_up
 
-__all__ = ["BamArray", "BamState", "BamKVStore", "PrefetchConfig",
+__all__ = ["BamArray", "BamState", "BamKVStore", "KVState", "KVToken",
+           "PrefetchConfig",
            "TenantCtx", "TenantSpec", "BamRuntime", "RuntimeState",
            "IORequest", "IOToken", "DEFAULT_BUCKETS", "OpFamilyEntry"]
 
@@ -162,6 +163,23 @@ def _mark_redeemed(token: "IOToken") -> None:
             "waited exactly once (a second wait would over-release its "
             "cache pins)")
     object.__setattr__(token, "_redeemed", True)
+
+
+def _guarded_wait(cache: Dict[str, Any], key: str, fn,
+                  io_of=lambda tok: tok):
+    """The cached host wrapper (cache key ``key + "#guard"``) of the
+    jitted wait ``fn`` that enforces the single-redemption contract on the
+    token's :class:`IOToken` (``io_of(tok)``; :func:`_mark_redeemed`)
+    before dispatch.  Shared by every ``wait_jit(guard=True)``."""
+    wkey = key + "#guard"
+    w = cache.get(wkey)
+    if w is None:
+        def guarded(st, tok, _fn=fn):
+            _mark_redeemed(io_of(tok))
+            return _fn(st, tok)
+
+        cache[wkey] = w = guarded
+    return w
 
 
 @dataclasses.dataclass(frozen=True)
@@ -476,17 +494,7 @@ class BamArray:
         fn = self._jit_op(
             key, lambda: lambda st, tok: self.wait(st, tok),
             donate_argnums=(0,) if donate else ())
-        if not guard:
-            return fn
-        wkey = key + "#guard"
-        w = self._jit_ops.get(wkey)
-        if w is None:
-            def guarded(st, tok, _fn=fn):
-                _mark_redeemed(tok)
-                return _fn(st, tok)
-
-            self._jit_ops[wkey] = w = guarded
-        return w
+        return _guarded_wait(self._jit_ops, key, fn) if guard else fn
 
     def submit_wait_jit(self, *, donate: bool = False):
         """Cached ``jax.jit`` of a whole submit → wait round
@@ -1634,6 +1642,27 @@ class BamArray:
                         storage=new_storage)
 
 
+@pytree_dataclass
+class KVState:
+    """All mutable state of a :class:`BamKVStore`: the values'
+    :class:`BamState` and the device-resident index (``table[s]`` is the
+    key placed in slot ``s``, ``-1`` where the slot is empty)."""
+
+    bam: BamState
+    table: jax.Array                # (capacity,) int32
+
+
+@pytree_dataclass
+class KVToken:
+    """Future returned by :meth:`BamKVStore.submit`: the value rows'
+    :class:`IOToken` and the keys' found mask, which is known at submit (the
+    index is device memory).  Redeem exactly once with
+    :meth:`BamKVStore.wait`."""
+
+    io: IOToken
+    found: jax.Array                # (n_keys,) bool
+
+
 @dataclasses.dataclass
 class BamKVStore:
     """Key-value abstraction: device-resident open-addressed index over
@@ -1641,75 +1670,124 @@ class BamKVStore:
 
     The index (one int32 per capacity slot) is small and lives in device
     memory; the values — the massive structure — live behind a
-    :class:`BamArray`.  This is exactly the split used by the framework's
-    on-demand embedding feature.
+    :class:`BamArray`, one value row per index slot.  This is exactly the
+    split used by the framework's on-demand embedding feature.
+
+    Lookups ride the array's token API: :meth:`submit` ``(KVState, keys)
+    -> (KVState, KVToken)`` probes the index (``kv_index`` scope) and
+    submits the found keys' value rows as one element wavefront;
+    :meth:`wait` ``(KVState, KVToken) -> (KVState, values)`` redeems it.
+    ``probes`` is the lookup window: every key of the built index lies
+    within ``probes`` slots of its home slot (:meth:`place`).  It has no
+    default: it is the built table's, and :meth:`state` refuses a table
+    that needs a longer one.
     """
 
     array: BamArray                 # values: (capacity, value_elems) flattened
     capacity: int
     value_elems: int
-    probes: int = 8
+    probes: int
+    _jit_ops: Dict[str, Any] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
+    _trace_counts: Dict[str, int] = dataclasses.field(
+        default_factory=dict, repr=False, compare=False)
 
     @staticmethod
-    def _hash_host(key: int, capacity: int) -> int:
-        """The shared hash: Knuth multiply with a uint32 wrap, then mod.
+    def _hash_host(keys, capacity: int):
+        """The shared hash on the host (numpy, scalar or array): Knuth
+        multiply with a uint32 wrap, then mod.
 
-        ``lookup`` computes the identical quantity in uint32 arithmetic
+        The lookup computes the identical quantity in uint32 arithmetic
         (:meth:`_hash_traced`); the wrap must happen *before* the modulo on
         both sides or roughly half of all keys (those whose wrapped product
         lands >= 2^31) probe different slots at build vs lookup time.  No
         ``abs`` anywhere: ``abs(INT32_MIN)`` is itself negative in int32.
         """
-        return ((int(key) & 0xFFFFFFFF) * 2654435761 & 0xFFFFFFFF) % capacity
+        import numpy as np
+        k = np.asarray(keys, np.int64).astype(np.uint64) & np.uint64(0xFFFFFFFF)
+        return (k * np.uint64(2654435761) & np.uint64(0xFFFFFFFF)) \
+            % np.uint64(capacity)
 
     def _hash_traced(self, keys: jax.Array) -> jax.Array:
-        """uint32-wrap hash of a key wavefront -> int32 slots in [0, cap)."""
+        """uint32-wrap hash of a key wavefront -> uint32 slots in [0, cap)."""
         h = keys.astype(jnp.uint32) * jnp.uint32(2654435761)
-        return (h % jnp.uint32(self.capacity)).astype(jnp.int32)
+        return h % jnp.uint32(self.capacity)
+
+    @staticmethod
+    def place(keys, *, capacity: int | None = None,
+              probes: int | None = None):
+        """Linear-probing placement of ``keys`` into ``capacity`` slots, on
+        the host and vectorised: returns ``(table, slots, probes)``, where
+        ``table[s]`` is the key placed in slot ``s`` (``-1`` empty),
+        ``slots[i]`` the slot of ``keys[i]`` (a repeated key has one slot),
+        and ``probes`` the lookup window the table needs: its longest
+        displacement from a home slot, plus one.
+
+        Every unplaced key tries ``(home + d) % capacity`` in round ``d``;
+        of the keys that try one empty slot, the least key takes it and the
+        rest step on with the keys that found theirs taken.  Any set of up
+        to ``capacity`` keys is placed.  An explicit ``probes`` rejects a
+        set that would need a longer window.
+        """
+        import numpy as np
+        keys = np.asarray(keys, np.int32)
+        if np.any(keys == -1):
+            raise ValueError("key -1 is reserved as the empty-slot sentinel")
+        uniq, inverse = np.unique(keys, return_inverse=True)
+        capacity = capacity or max(2 * keys.shape[0], 16)
+        if uniq.shape[0] > capacity:
+            raise ValueError(f"kv store: {uniq.shape[0]} keys do not fit "
+                             f"capacity {capacity}")
+        home = BamKVStore._hash_host(uniq, capacity).astype(np.int64)
+        table = np.full((capacity,), -1, np.int32)
+        slot_u = np.empty(uniq.shape, np.int64)
+        todo = np.arange(uniq.shape[0])
+        rounds = 0
+        while todo.size:
+            if probes is not None and rounds == probes:
+                raise ValueError(
+                    f"kv store: key {int(uniq[todo[0]])} cannot be placed "
+                    f"within probes={probes} slots of its home slot; raise "
+                    "capacity or probes")
+            s = (home[todo] + rounds) % capacity
+            free = table[s] == -1
+            # uniq is sorted, so the first claimant of a slot is its least key
+            ws, first = np.unique(s[free], return_index=True)
+            won = todo[free][first]
+            table[ws] = uniq[won]
+            slot_u[won] = ws
+            placed = np.zeros(uniq.shape, bool)
+            placed[won] = True
+            todo = todo[~placed[todo]]
+            rounds += 1
+        return table, slot_u[inverse.reshape(-1)], max(rounds, 1)
 
     @staticmethod
     def build_table(keys, values, *, capacity: int | None = None,
-                    probes: int = 8):
-        """Host-side open-addressing placement shared by :meth:`build` and
-        the multi-tenant runtime: returns ``(table, store_vals, capacity)``
-        where ``store_vals[(hash(k)+j) % capacity]`` holds key ``k``'s
-        value row."""
+                    probes: int | None = None):
+        """Host-side build shared by :meth:`build` and the multi-tenant
+        runtime: returns ``(table, store_vals, capacity, probes)`` where
+        ``store_vals[slot]`` holds the value row of the key placed in
+        ``slot`` (:meth:`place`; a repeated key keeps its last row)."""
         import numpy as np
-        keys = np.asarray(keys, np.int32)
         values = np.asarray(values)
-        n, value_elems = values.shape
-        capacity = capacity or max(2 * n, 16)
-        table = np.full((capacity,), -1, np.int32)     # key per slot
-        store_vals = np.zeros((capacity, value_elems), values.dtype)
-        for i, k in enumerate(keys):
-            if k == -1:
-                raise ValueError(
-                    "key -1 is reserved as the empty-slot sentinel")
-            h = BamKVStore._hash_host(k, capacity)
-            # Place within lookup's probe window only: a key parked further
-            # out would be silently unfindable (lookup unrolls `probes`
-            # slots) — fail loudly instead.
-            for j in range(min(probes, capacity)):
-                s = (h + j) % capacity
-                if table[s] == -1 or table[s] == k:
-                    table[s] = k
-                    store_vals[s] = values[i]
-                    break
-            else:
-                raise ValueError(
-                    f"kv store: key {int(k)} cannot be placed within "
-                    f"probes={probes} slots of its home slot; raise "
-                    "capacity or probes")
-        return table, store_vals, capacity
+        table, slots, probes = BamKVStore.place(keys, capacity=capacity,
+                                                probes=probes)
+        rows = slots.shape[0] - 1 - np.unique(slots[::-1],
+                                              return_index=True)[1]
+        store_vals = np.zeros((table.shape[0], values.shape[1]),
+                              values.dtype)
+        store_vals[slots[rows]] = values[rows]
+        return table, store_vals, table.shape[0], probes
 
     @staticmethod
     def build(keys, values, *, capacity: int | None = None,
-              probes: int = 8, **bam_kw):
+              probes: int | None = None, **bam_kw):
         """Host-side bulk build; returns (kv, index_table, BamState)."""
         import numpy as np
         values = np.asarray(values)
         _, value_elems = values.shape
-        table, store_vals, capacity = BamKVStore.build_table(
+        table, store_vals, capacity, probes = BamKVStore.build_table(
             keys, values, capacity=capacity, probes=probes)
         bam_kw.setdefault("block_elems", value_elems)
         arr, st = BamArray.build(store_vals, **bam_kw)
@@ -1717,46 +1795,156 @@ class BamKVStore:
                         value_elems=value_elems, probes=probes)
         return kv, jnp.asarray(table), st
 
-    def lookup_submit(self, st: BamState, table: jax.Array, keys: jax.Array
-                      ) -> Tuple[BamState, IOToken, jax.Array]:
-        """Asynchronous lookup, submission half: probe the device-resident
-        index and *submit* the value gather, returning ``(state', token,
-        found_mask)``.  The found mask is available immediately (the index
-        is device memory); the values arrive at :meth:`lookup_wait`.
-        Several lookups' tokens may be outstanding at once — duplicate hot
-        keys across pending lookups coalesce onto one storage fetch.
-        """
-        cap = self.capacity
-        h = self._hash_traced(keys)
-        slot = jnp.full_like(keys, -1)
-        for j in range(self.probes):                   # static unroll, small
-            s = (h + j) % cap
-            match = (table[s] == keys) & (slot < 0)
-            slot = jnp.where(match, s, slot)
-        # key -1 would "match" every empty slot (the sentinel); never found.
-        found = (slot >= 0) & (keys != -1)
-        base = jnp.where(found, slot, 0) * self.value_elems
-        # one wavefront read per value element column (value_elems small) —
-        # flatten to a single wavefront of element indices instead:
-        idx = (base[:, None] + jnp.arange(self.value_elems)[None, :]).reshape(-1)
-        vmask = jnp.repeat(found, self.value_elems)
-        st, tok = self.array.submit(st, IORequest.read(idx, vmask))
-        return st, tok, found
+    @staticmethod
+    def window(table) -> int:
+        """The lookup window ``table`` needs: the longest displacement of
+        one of its keys from its home slot, plus one (1 if empty)."""
+        import numpy as np
+        table = np.asarray(table)
+        slots = np.flatnonzero(table != -1)
+        if slots.size == 0:
+            return 1
+        home = BamKVStore._hash_host(table[slots], table.shape[0])
+        return int(((slots - home.astype(np.int64))
+                    % table.shape[0]).max()) + 1
 
-    def lookup_wait(self, st: BamState, token: IOToken
-                    ) -> Tuple[BamState, jax.Array]:
-        """Redeem a :meth:`lookup_submit` token: ``(state', values)`` with
-        values shaped ``(n_keys, value_elems)``."""
-        st, flat = self.array.wait(st, token)
-        return st, flat.reshape(-1, self.value_elems)
+    def state(self, bam: BamState, table) -> KVState:
+        """The store's :class:`KVState` over the values' ``bam`` and the
+        index ``table`` (concrete, made outside any trace).  Raises if the
+        table is not ``capacity`` slots or needs a longer window than the
+        store's ``probes``: a lookup would miss the keys beyond it."""
+        import numpy as np
+        if isinstance(table, jax.core.Tracer):
+            raise TypeError("kv store: make the KVState outside the trace")
+        if np.shape(table) != (self.capacity,):
+            raise ValueError(f"kv store: index of shape {np.shape(table)}, "
+                             f"capacity {self.capacity}")
+        # host-only from here on (tracers refused above): a numpy int
+        need = BamKVStore.window(table)
+        if need > self.probes:  # bamlint: ignore[BAM104]
+            raise ValueError(
+                f"kv store: the index needs a window of {need} slots but "
+                f"the store reads probes={self.probes}; pass the window "
+                "place() returns")
+        return KVState(bam=bam, table=jnp.asarray(table, jnp.int32))
+
+    # ------------------------------------------------------------ token API
+    @property
+    def storage(self):
+        """The values' block store (the array's)."""
+        return self.array.storage
+
+    @property
+    def trace_counts(self) -> Dict[str, int]:
+        """How many times each jit-cached lookup op has been traced."""
+        return dict(self._trace_counts)
+
+    def _jit_op(self, name: str, make, donate_argnums=()):
+        """See :func:`_cached_jit` (the shared cache + retrace probe)."""
+        return _cached_jit(self._jit_ops, self._trace_counts, name, make,
+                           donate_argnums=donate_argnums)
+
+    def submit_jit(self, *, donate: bool = False):
+        """Cached ``jax.jit`` of :meth:`submit` ``(kst, keys) -> (kst,
+        tok)``; the jitted op is ``bam_submit_kv[_donated]``.  ``donate``
+        as in :meth:`BamArray.submit_jit`."""
+        key = "submit:kv[donated]" if donate else "submit:kv"
+        return self._jit_op(
+            key, lambda: lambda kst, keys: self.submit(kst, keys),
+            donate_argnums=(0,) if donate else ())
+
+    def wait_jit(self, *, donate: bool = False, guard: bool = True):
+        """Cached ``jax.jit`` of :meth:`wait` ``(kst, tok) -> (kst,
+        values)``; ``donate`` and ``guard`` as in
+        :meth:`BamArray.wait_jit`."""
+        key = "wait:kv[donated]" if donate else "wait:kv"
+        fn = self._jit_op(
+            key, lambda: lambda kst, tok: self.wait(kst, tok),
+            donate_argnums=(0,) if donate else ())
+        return (_guarded_wait(self._jit_ops, key, fn, lambda tok: tok.io)
+                if guard else fn)
+
+    def _probe(self, table: jax.Array, keys: jax.Array) -> jax.Array:
+        """Slot of each key (``-1``: not found): one gather of the
+        ``probes`` slots from each key's home slot, first match."""
+        win = ((self._hash_traced(keys)[:, None]
+                + jnp.arange(self.probes, dtype=jnp.uint32)[None, :])
+               % jnp.uint32(self.capacity)).astype(jnp.int32)
+        match = table[win] == keys[:, None]
+        # key -1 would "match" every empty slot (the sentinel); never found.
+        found = jnp.any(match, axis=1) & (keys != -1)
+        first = jnp.argmax(match, axis=1)
+        slot = jnp.take_along_axis(win, first[:, None], axis=1)[:, 0]
+        return jnp.where(found, slot, -1)
+
+    def submit(self, kst: KVState, keys: jax.Array
+               ) -> Tuple[KVState, KVToken]:
+        """Probe the index and *submit* the found keys' value rows as one
+        wavefront of element reads.  The found mask rides the token at
+        once; the values arrive at :meth:`wait`.  Several lookups' tokens
+        may be outstanding at once — duplicate hot keys across pending
+        lookups coalesce onto one storage fetch."""
+        ve = self.value_elems
+        with jax.named_scope("kv_index"):
+            slot = self._probe(kst.table, keys)
+            found = slot >= 0
+            idx = (jnp.where(found, slot, 0)[:, None] * ve
+                   + jnp.arange(ve, dtype=jnp.int32)[None, :]).reshape(-1)
+            vmask = jnp.repeat(found, ve)
+        st, io = self.array.submit(kst.bam, IORequest.read(idx, vmask))
+        with jax.named_scope("kv_index"):
+            mt = st.metrics
+            n_keys = jnp.sum((keys != -1).astype(jnp.int32))
+            st = dataclasses.replace(st, metrics=dataclasses.replace(
+                mt, kv_lookups=mt.kv_lookups + n_keys,
+                kv_probe_slots=mt.kv_probe_slots + n_keys * self.probes))
+        return KVState(bam=st, table=kst.table), KVToken(io=io, found=found)
+
+    def wait(self, kst: KVState, tok: KVToken
+             ) -> Tuple[KVState, jax.Array]:
+        """Redeem a :meth:`submit` token: ``(kst', values)`` with values
+        shaped ``(n_keys, value_elems)`` (zeros where not found)."""
+        st, flat = self.array.wait(kst.bam, tok.io)
+        return (KVState(bam=st, table=kst.table),
+                flat.reshape(-1, self.value_elems))
 
     def lookup(self, st: BamState, table: jax.Array, keys: jax.Array
                ) -> Tuple[jax.Array, jax.Array, BamState]:
         """Return (values, found_mask, state') for a wavefront of keys
-        (synchronous shim: ``lookup_submit`` + ``lookup_wait``)."""
-        st, tok, found = self.lookup_submit(st, table, keys)
-        st, vals = self.lookup_wait(st, tok)
-        return vals, found, st
+        (synchronous shim: :meth:`submit` + :meth:`wait` over
+        :meth:`state`)."""
+        kst, tok = self.submit(self.state(st, table), keys)
+        kst, vals = self.wait(kst, tok)
+        return vals, tok.found, kst.bam
+
+    def iter_op_family(self):
+        """Enumerate the store's jit-cached token ops (see
+        :class:`OpFamilyEntry` and :meth:`BamArray.iter_op_family`).
+        ``example_args`` take a :class:`KVState`; a batch of ``n`` lanes is
+        ``n // value_elems`` keys, so the value wavefront is ``n`` lanes as
+        in the array's family."""
+        import numpy as np
+
+        def args_keys(kst, n):
+            table = np.asarray(kst.table)
+            present = table[table != -1]
+            nk = max(n // self.value_elems, 1)
+            return (kst, jnp.asarray(present[np.arange(nk) % present.size]))
+
+        def args_token(kst, n):
+            kst1, tok = self.submit(*args_keys(kst, n))
+            return (kst1, tok)
+
+        # both ride the array's lax.cond-gated write-back and fetch
+        yield OpFamilyEntry(
+            name="submit:kv", donatable=True, pure_all_hit=True,
+            get=lambda donate=False: self.submit_jit(donate=donate),
+            example_args=args_keys, trace_keys=("submit:kv",))
+        yield OpFamilyEntry(
+            name="wait:kv", donatable=True, pure_all_hit=True,
+            get=lambda donate=False: self.wait_jit(donate=donate,
+                                                   guard=False),
+            example_args=args_token, trace_keys=("wait:kv",))
 
 
 # ===================================================================== runtime
@@ -2047,17 +2235,7 @@ class BamRuntime:
         fn = self._jit_op(
             key, lambda: lambda rst, tok: self.wait(rst, name, tok),
             donate_argnums=(0,) if donate else ())
-        if not guard:
-            return fn
-        wkey = key + "#guard"
-        w = self._jit_ops.get(wkey)
-        if w is None:
-            def guarded(rst, tok, _fn=fn):
-                _mark_redeemed(tok)
-                return _fn(rst, tok)
-
-            self._jit_ops[wkey] = w = guarded
-        return w
+        return _guarded_wait(self._jit_ops, key, fn) if guard else fn
 
     def iter_op_family(self):
         """Enumerate the runtime's per-tenant jit-cached op family (see

@@ -53,6 +53,9 @@ class IOMetrics:
     retries: jax.Array           # command re-issues (bounded by retry_budget)
     failed_commands: jax.Array   # commands retired with an error status
     degraded_reads: jax.Array    # element lanes redeemed with error_mask set
+    # Key-value index accounting (BamKVStore; zero on the array path).
+    kv_lookups: jax.Array        # valid keys looked up in the device index
+    kv_probe_slots: jax.Array    # index slots read by those lookups
     # Per-device channel breakdown, all shape (n_devices,).
     dev_reads: jax.Array         # lines fetched per device (demand + readahead)
     dev_writes: jax.Array        # lines written back per device
@@ -77,7 +80,7 @@ class IOMetrics:
             tokens_submitted=f(), tokens_waited=f(), tokens_in_flight=f(),
             cross_op_coalesced=f(), max_tokens_in_flight=i(),
             transient_errors=f(), retries=f(), failed_commands=f(),
-            degraded_reads=f(),
+            degraded_reads=f(), kv_lookups=f(), kv_probe_slots=f(),
             dev_reads=jnp.zeros((n_devices,), ftype),
             dev_writes=jnp.zeros((n_devices,), ftype),
             dev_bytes=jnp.zeros((n_devices,), ftype),
@@ -159,6 +162,8 @@ class IOMetrics:
             "retries": float(self.retries),
             "failed_commands": float(self.failed_commands),
             "degraded_reads": float(self.degraded_reads),
+            "kv_lookups": float(self.kv_lookups),
+            "kv_probe_slots": float(self.kv_probe_slots),
             "n_devices": self.n_devices,
             "dev_reads": [float(x) for x in jax.device_get(self.dev_reads)],
             "dev_writes": [float(x) for x in jax.device_get(self.dev_writes)],

@@ -106,12 +106,8 @@ def test_manifest_mutation_detected_per_op_and_bucket():
 def family():
     """Lower the whole op family once (the expensive part) and share the
     artifacts across every live test."""
-    from tools.bamverify.lowering import (
-        canonical_array, canonical_runtime, collect_stats, lower_op_family,
-    )
-    arr, st = canonical_array()
-    rt, rst = canonical_runtime()
-    artifacts = lower_op_family(arr, st) + lower_op_family(rt, rst)
+    from tools.bamverify.lowering import collect_stats, lower_all
+    artifacts = lower_all()
     return {"artifacts": artifacts, "stats": collect_stats(artifacts)}
 
 
@@ -231,7 +227,9 @@ def test_iter_op_family_covers_the_jit_surface():
     """The registry is the verifier's ground truth: it must enumerate the
     ops, mark donatable ones, and claim purity for submit and wait (both
     gate their host callbacks)."""
-    from tools.bamverify.lowering import canonical_array, canonical_runtime
+    from tools.bamverify.lowering import (
+        canonical_array, canonical_kv, canonical_runtime,
+    )
 
     arr, _st = canonical_array()
     entries = {e.name: e for e in arr.iter_op_family()}
@@ -250,6 +248,11 @@ def test_iter_op_family_covers_the_jit_surface():
         assert rentries[f"submit:{tenant}"].donatable
         assert rentries[f"submit:{tenant}"].pure_all_hit
         assert rentries[f"wait:{tenant}"].pure_all_hit
+
+    kv, _kst = canonical_kv()
+    kentries = {e.name: e for e in kv.iter_op_family()}
+    assert set(kentries) == {"submit:kv", "wait:kv"}
+    assert all(e.donatable and e.pure_all_hit for e in kentries.values())
 
 
 # -------------------------------------------------------- CLI exit codes
@@ -283,11 +286,7 @@ def _patched_main(monkeypatch, family, argv):
     artifacts (so exit-code tests don't pay a second full lowering)."""
     from tools.bamverify import __main__ as M
     from tools.bamverify import lowering as L
-    calls = iter([family["artifacts"], []])
-    monkeypatch.setattr(L, "canonical_array", lambda: (None, None))
-    monkeypatch.setattr(L, "canonical_runtime", lambda: (None, None))
-    monkeypatch.setattr(L, "lower_op_family",
-                        lambda owner, st: next(calls))
+    monkeypatch.setattr(L, "lower_all", lambda: family["artifacts"])
     monkeypatch.setattr(L, "sweep_bucketed", lambda: [])
     return M.main(argv)
 

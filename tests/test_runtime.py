@@ -127,12 +127,13 @@ def test_queue_accounting_per_tenant_after_ops():
 def test_kv_store_rides_a_runtime_tenant():
     keys = np.arange(64, dtype=np.int32)
     values = np.arange(64 * BE, dtype=np.float32).reshape(64, BE)
-    table, store_vals, cap = BamKVStore.build_table(keys, values,
-                                                    capacity=128)
+    table, store_vals, cap, probes = BamKVStore.build_table(
+        keys, values, capacity=128)
     specs = [TenantSpec("kv", store_vals, block_elems=BE),
              TenantSpec("other", np.zeros(64, np.float32), block_elems=BE)]
     rt, rst = BamRuntime.build(specs, num_sets=8, ways=4)
-    kv = BamKVStore(array=rt.array("kv"), capacity=cap, value_elems=BE)
+    kv = BamKVStore(array=rt.array("kv"), capacity=cap, value_elems=BE,
+                    probes=probes)
     tj = jnp.asarray(table)
     st = rt.tenant_view(rst, "kv")
     vals, found, st = kv.lookup(st, tj, jnp.asarray([0, 17, 63, 99],

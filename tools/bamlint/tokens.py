@@ -16,7 +16,7 @@ twice on one path" (double wait).
 
 Rules
 -----
-BAM201  token leak: a ``submit``/``lookup_submit`` result bound to a name
+BAM201  token leak: a ``submit`` result bound to a name
         that is never waited, returned, stored, or passed on.
 BAM202  double wait: the same token binding waited more than once on a
         single path (including once-per-iteration waits on a token bound
@@ -39,8 +39,8 @@ RULES = {
     "BAM203": "cache pins acquired without release / IOToken hand-off",
 }
 
-SUBMIT_TAILS = ("submit", "lookup_submit")
-WAIT_TAILS = ("wait", "lookup_wait")
+SUBMIT_TAILS = ("submit",)
+WAIT_TAILS = ("wait",)
 
 
 def _functions(tree: ast.Module):

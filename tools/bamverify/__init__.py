@@ -2,11 +2,12 @@
 
 bamlint (``tools/bamlint``) lints *source*; bamverify lints what XLA
 actually *emitted*.  It enumerates the jit-cached op family via the
-``iter_op_family()`` registry on ``BamArray``/``BamRuntime``, lowers each
-op at canonical bucket shapes on the CPU backend, and checks the BAM5xx
-rules against the compiled HLO text — silent donation drops, dtype creep,
-callbacks escaping their ``lax.cond`` gate, scatter-count regressions,
-and shape-bucketing executable leaks.  It then diffs a committed
+``iter_op_family()`` registry on ``BamArray``/``BamRuntime``/
+``BamKVStore``, lowers each op at canonical bucket shapes on the CPU
+backend, and checks the BAM5xx rules against the compiled HLO text —
+silent donation drops, dtype creep, callbacks escaping their
+``lax.cond`` gate, scatter-count regressions, and shape-bucketing
+executable leaks.  It then diffs a committed
 **artifact manifest** (``tools/bamverify/manifest.json``: per op x bucket
 -> scatter count, while-loop count, donation aliases, dtypes,
 instruction count) so perf-relevant compiled-graph regressions are

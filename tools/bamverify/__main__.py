@@ -66,12 +66,9 @@ def main(argv=None) -> int:
         # JAX import + lowering live behind the CLI entry so --list-rules
         # and usage errors never need the heavy dependency.
         from tools.bamverify.lowering import (
-            canonical_array, canonical_runtime, collect_stats,
-            lower_op_family, sweep_bucketed,
+            collect_stats, lower_all, sweep_bucketed,
         )
-        arr, st = canonical_array()
-        rt, rst = canonical_runtime()
-        artifacts = lower_op_family(arr, st) + lower_op_family(rt, rst)
+        artifacts = lower_all()
         stats = collect_stats(artifacts)
     except Exception as e:                      # lowering is internal
         print(f"bamverify: internal error while lowering the op family: "

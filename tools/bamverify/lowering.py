@@ -24,7 +24,7 @@ import jax                                    # noqa: E402
 import numpy as np                            # noqa: E402
 
 from repro.core.bam_array import (            # noqa: E402
-    BamArray, BamRuntime, TenantSpec,
+    BamArray, BamKVStore, BamRuntime, TenantSpec,
 )
 from tools.bamverify.rules import (           # noqa: E402
     ArtifactSpec, ArtifactStats, Finding, analyze_artifact,
@@ -54,6 +54,32 @@ def canonical_runtime() -> Tuple[BamRuntime, object]:
         [TenantSpec("a", a, block_elems=16),
          TenantSpec("b", b, block_elems=16)],
         num_sets=8, ways=4, num_queues=4, queue_depth=128)
+
+
+def canonical_kv() -> Tuple[BamKVStore, object]:
+    """A key-value store of 64 keys, one 16-element value a line, and its
+    :class:`KVState`, for the store's token ops."""
+    keys = np.arange(64, dtype=np.int32) * 7 + 1
+    values = np.arange(64 * 16, dtype=np.float32).reshape(64, 16)
+    kv, table, st = BamKVStore.build(keys, values, capacity=128,
+                                     num_sets=16, ways=4, num_queues=4,
+                                     queue_depth=256)
+    return kv, kv.state(st, table)
+
+
+# The store's token ops are the array's submit/wait behind the index
+# probe, so one bucket shows their structure.
+KV_BUCKETS: Tuple[int, ...] = CANONICAL_BUCKETS[:1]
+
+
+def lower_all() -> List[Tuple[ArtifactSpec, str]]:
+    """Lower every registry: the canonical array's, runtime's and key-value
+    store's op families."""
+    arr, st = canonical_array()
+    rt, rst = canonical_runtime()
+    kv, kst = canonical_kv()
+    return (lower_op_family(arr, st) + lower_op_family(rt, rst)
+            + lower_op_family(kv, kst, KV_BUCKETS))
 
 
 def lower_op_family(owner, state,
