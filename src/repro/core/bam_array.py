@@ -64,6 +64,7 @@ exactly to the global counters.
 from __future__ import annotations
 
 import dataclasses
+import math
 import re
 from typing import Any, Dict, Optional, Sequence, Tuple
 
@@ -240,7 +241,7 @@ class BamState:
     storage: Any  # HBMStorage pytree for the in-graph backend, else None
 
 
-@pytree_dataclass(meta_fields=("kind",))
+@pytree_dataclass(meta_fields=("kind", "lines"))
 class IORequest:
     """Unified op descriptor: read / write / prefetch share one submission
     path (:meth:`BamArray.submit`).
@@ -249,16 +250,27 @@ class IORequest:
     (``None`` = in-bounds check); ``values`` carries the write payload for
     ``kind="write"``.  Build with the :meth:`read`/:meth:`write`/
     :meth:`prefetch` constructors rather than the raw dataclass.
+
+    ``lines`` is a static bound ``U`` on the number of distinct cache lines
+    the wavefront can touch; the token's line-sized rows (``ukeys``,
+    ``pin_slots``, ``ticket``) and every stage after the coalescer are then
+    ``min(U, n)`` wide instead of ``n``.  It must be a *proof* from the
+    request's construction (:meth:`BamKVStore.submit` derives it from its
+    key count and value layout), never a guess: a wavefront with more than
+    ``U`` distinct lines would lose the ones past ``U``.  ``None`` means
+    ``n``, one line a lane.
     """
 
     kind: str                       # "read" | "write" | "prefetch"
     idx: jax.Array                  # (n,) element indices
     values: jax.Array | None = None  # (n,) write payload (kind="write")
     valid: jax.Array | None = None  # (n,) lane mask; None = bounds check
+    lines: int | None = None        # static bound on distinct lines; None = n
 
     @staticmethod
-    def read(idx: jax.Array, valid: jax.Array | None = None) -> "IORequest":
-        return IORequest(kind="read", idx=idx, valid=valid)
+    def read(idx: jax.Array, valid: jax.Array | None = None,
+             lines: int | None = None) -> "IORequest":
+        return IORequest(kind="read", idx=idx, valid=valid, lines=lines)
 
     @staticmethod
     def write(idx: jax.Array, values: jax.Array,
@@ -282,14 +294,18 @@ class IOToken:
     payload, and the per-device command histograms for the accounting that
     is deferred to the drain.  Dropping a token without waiting it leaks
     its cache pins; waiting it twice over-releases them.
+
+    Lane-sized fields are ``(n,)``, one row per request lane; line-sized
+    fields are ``(U,)``, one row per unique line, where ``U = min(n,
+    IORequest.lines)`` (``U = n`` when the request gives no bound).
     """
 
     kind: str                       # mirrors the IORequest kind
     valid: jax.Array                # (n,) request lanes
     off: jax.Array                  # (n,) element offset within its line
-    inverse: jax.Array              # (n,) request lane -> unique-key row
-    ukeys: jax.Array                # (n,) coalesced block keys, -1 padded
-    pin_slots: jax.Array            # (n,) flat slots pinned at submit (-1 none)
+    inverse: jax.Array              # (n,) request lane -> unique-key row (< U)
+    ukeys: jax.Array                # (U,) coalesced block keys, -1 padded
+    pin_slots: jax.Array            # (U,) flat slots pinned at submit (-1 none)
     values: jax.Array | None        # (n,) write payload (kind="write")
     ra_keys: jax.Array | None       # (window,) stride-readahead keys issued
     dev_reads: jax.Array            # (nd,) read commands issued (incl. dropped)
@@ -302,7 +318,7 @@ class IOToken:
     # the command's retry/error fate from (device, ticket) with the same
     # pure FaultModel.command_status the drain uses, so the two agree by
     # construction.
-    ticket: jax.Array               # (n,) int32
+    ticket: jax.Array               # (U,) int32
     ra_ticket: jax.Array | None     # (window,) readahead command tickets
     # Back-pressure visibility (satellite: drops must not be silent): True
     # on every request lane whose unique line's command the rings rejected
@@ -556,7 +572,8 @@ class BamArray:
         if req.values is not None:
             values = pad_to(req.values, m, 0)
         req = IORequest(kind=req.kind, idx=pad_to(req.idx, m, -1),
-                        values=values, valid=pad_to(valid, m, False))
+                        values=values, valid=pad_to(valid, m, False),
+                        lines=req.lines)
         st2, tok = self.submit_jit(donate=donate)(st, req)
         # host-side bookkeeping (not a pytree leaf): wait_bucketed slices
         # the values back to the caller's length
@@ -716,6 +733,10 @@ class BamArray:
             raise ValueError("IORequest(kind='write') needs values")
         if req.idx.shape[0] == 0:
             return self._submit_empty(st, req)
+        # bamlint: ignore[BAM104] -- ``lines`` is a static meta field
+        if req.lines is not None and not 0 < req.lines:
+            raise ValueError(f"IORequest.lines must be positive, got "
+                             f"{req.lines}")
         with jax.named_scope("coalesce"):
             idx = req.idx
             valid = req.valid
@@ -726,7 +747,13 @@ class BamArray:
 
             # 1) warp-coalesce the wavefront to unique cache lines.
             co = coalesce(blk, valid)
-            ukeys = co.unique_keys                      # (n,) padded with -1
+            # the unique keys are packed first and there are at most
+            # ``lines`` of them, so every row past it is -1 padding
+            # bamlint: ignore[BAM104] -- ``lines`` is a static meta field
+            if req.lines is not None and req.lines < blk.shape[0]:
+                co = dataclasses.replace(
+                    co, unique_keys=co.unique_keys[:req.lines])
+            ukeys = co.unique_keys                      # (U,) padded with -1
             uvalid = ukeys >= 0
             ctx = self.tenant_ctx
             nd = self.ssd.n_devices
@@ -1864,6 +1891,16 @@ class BamKVStore:
         return (_guarded_wait(self._jit_ops, key, fn, lambda tok: tok.io)
                 if guard else fn)
 
+    @property
+    def lines_per_value(self) -> int:
+        """The most cache lines one value row can touch.  Row ``s`` starts
+        at element ``s * ve``, at an offset within its line that is a
+        multiple of ``g = gcd(ve, be)`` and at most ``be - g``, so it spans
+        at most ``ceil((ve + be - g) / be)`` lines (``ve // be`` when
+        ``be`` divides ``ve``)."""
+        ve, be = self.value_elems, self.array.block_elems
+        return -(-(ve + be - math.gcd(ve, be)) // be)
+
     def _probe(self, table: jax.Array, keys: jax.Array) -> jax.Array:
         """Slot of each key (``-1``: not found): one gather of the
         ``probes`` slots from each key's home slot, first match."""
@@ -1877,21 +1914,31 @@ class BamKVStore:
         slot = jnp.take_along_axis(win, first[:, None], axis=1)[:, 0]
         return jnp.where(found, slot, -1)
 
+    def _value_request(self, table: jax.Array, keys: jax.Array
+                       ) -> Tuple[jax.Array, IORequest]:
+        """``(found, request)``: the keys' found mask and the element read
+        of their value rows, bounded to ``lines_per_value`` lines a key."""
+        ve = self.value_elems
+        with jax.named_scope("kv_index"):
+            slot = self._probe(table, keys)
+            found = slot >= 0
+            idx = (jnp.where(found, slot, 0)[:, None] * ve
+                   + jnp.arange(ve, dtype=jnp.int32)[None, :]).reshape(-1)
+            vmask = jnp.repeat(found, ve)
+        return found, IORequest.read(
+            idx, vmask, lines=keys.shape[0] * self.lines_per_value)
+
     def submit(self, kst: KVState, keys: jax.Array
                ) -> Tuple[KVState, KVToken]:
         """Probe the index and *submit* the found keys' value rows as one
         wavefront of element reads.  The found mask rides the token at
         once; the values arrive at :meth:`wait`.  Several lookups' tokens
         may be outstanding at once — duplicate hot keys across pending
-        lookups coalesce onto one storage fetch."""
-        ve = self.value_elems
-        with jax.named_scope("kv_index"):
-            slot = self._probe(kst.table, keys)
-            found = slot >= 0
-            idx = (jnp.where(found, slot, 0)[:, None] * ve
-                   + jnp.arange(ve, dtype=jnp.int32)[None, :]).reshape(-1)
-            vmask = jnp.repeat(found, ve)
-        st, io = self.array.submit(kst.bam, IORequest.read(idx, vmask))
+        lookups coalesce onto one storage fetch.  The wavefront touches at
+        most ``n_keys * lines_per_value`` lines, so its token's line-sized
+        stages run at that width, not at ``n_keys * value_elems`` lanes."""
+        found, req = self._value_request(kst.table, keys)
+        st, io = self.array.submit(kst.bam, req)
         with jax.named_scope("kv_index"):
             mt = st.metrics
             n_keys = jnp.sum((keys != -1).astype(jnp.int32))
